@@ -272,4 +272,7 @@ def main(profiles=None, out_json: str | None = None) -> list[dict]:
 
 
 if __name__ == "__main__":
+    from repro.compat import use_compilation_cache
+
+    use_compilation_cache()
     main(out_json="BENCH_incremental.json")

@@ -1,9 +1,11 @@
-"""Pallas kernel microbench: interpret-mode wall time vs the jnp oracle.
+"""Pallas kernel microbench: wall time vs the jnp oracle.
 
-CPU interpret-mode timings do NOT reflect TPU performance (each grid step
-runs the kernel body in Python-driven XLA); the numbers here are a
-correctness + plumbing check.  The TPU-relevant analysis of these kernels is
-the BlockSpec/VMEM sizing in each kernel file and the §Roofline terms.
+The kernels run as Mosaic on a TPU and interpreted elsewhere
+(``repro.kernels.ops.interpret_mode``).  Interpreted timings do NOT reflect
+TPU performance (each grid step runs the kernel body in Python-driven XLA);
+off the TPU the numbers are a correctness + plumbing check.  The
+TPU-relevant analysis of these kernels is the BlockSpec/VMEM sizing in each
+kernel file and the §Roofline terms.
 """
 
 from __future__ import annotations
@@ -35,7 +37,7 @@ def main() -> list[dict]:
     idx = rng.integers(0, 1 << 13, (1 << 12,)).astype(np.int32)
     rows.append({
         "kernel": "pointer_jump",
-        "pallas_ms": _time(lambda a, b: ops.pointer_jump(a, b, interpret=True), idx, table),
+        "pallas_ms": _time(lambda a, b: ops.pointer_jump(a, b), idx, table),
         "ref_ms": _time(ref.pointer_jump_ref, idx, table),
     })
 
@@ -45,7 +47,7 @@ def main() -> list[dict]:
     rho[rng.integers(0, 1 << 13, 1 << 10)] = 0
     rows.append({
         "kernel": "rewrite_triples",
-        "pallas_ms": _time(lambda a, b: ops.rewrite_triples(a, b, interpret=True), spo, rho),
+        "pallas_ms": _time(lambda a, b: ops.rewrite_triples(a, b), spo, rho),
         "ref_ms": _time(ref.rewrite_triples_ref, spo, rho),
     })
 
@@ -54,7 +56,7 @@ def main() -> list[dict]:
     ids = rng.integers(0, 1 << 14, (1 << 10, 16)).astype(np.int32)
     rows.append({
         "kernel": "embedding_bag",
-        "pallas_ms": _time(lambda a, b: ops.embedding_bag(a, b, interpret=True), ids, table_f),
+        "pallas_ms": _time(lambda a, b: ops.embedding_bag(a, b), ids, table_f),
         "ref_ms": _time(ref.embedding_bag_ref, ids, table_f),
     })
 
@@ -62,15 +64,18 @@ def main() -> list[dict]:
     emb = rng.normal(size=(1 << 10, 39, 16)).astype(np.float32)
     rows.append({
         "kernel": "fm_interact",
-        "pallas_ms": _time(lambda a: ops.fm_interact(a, interpret=True), emb),
+        "pallas_ms": _time(lambda a: ops.fm_interact(a), emb),
         "ref_ms": _time(ref.fm_interact_ref, emb),
     })
 
-    print("kernel            pallas(interp)_ms     ref_ms")
+    print("kernel                 pallas_ms     ref_ms")
     for r in rows:
         print(f"{r['kernel']:17s} {r['pallas_ms']:14.2f} {r['ref_ms']:10.2f}")
     return rows
 
 
 if __name__ == "__main__":
+    from repro.compat import use_compilation_cache
+
+    use_compilation_cache()
     main()

@@ -55,6 +55,9 @@ _SCRIPT = textwrap.dedent(
 
 def run_cell(profile: str, n_dev: int) -> dict:
     env = dict(os.environ)
+    # fake CPU devices measure partitioning; pinning the child to the CPU
+    # also keeps it off an accelerator the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
     env["PYTHONPATH"] = "src"
     out = subprocess.run(
@@ -63,7 +66,10 @@ def run_cell(profile: str, n_dev: int) -> dict:
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
     if out.returncode != 0:
-        return {"profile": profile, "n_dev": n_dev, "error": out.stderr[-400:]}
+        raise RuntimeError(
+            f"bench_scaling {profile} on {n_dev} devices exited "
+            f"{out.returncode}:\n{out.stderr[-2000:]}"
+        )
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
@@ -74,9 +80,6 @@ def main(profiles=("claros_like", "opencyc_like"), shard_counts=(1, 2, 4)) -> li
         for n in shard_counts:
             r = run_cell(profile, n)
             rows.append(r)
-            if "error" in r:
-                print(f"{profile:14s} {n:6d}   ERROR {r['error'][:80]}")
-                continue
             print(
                 f"{profile:14s} {n:6d} {r['ax_s']:8.3f} {r['rew_np_s']:8.3f}"
                 f" {r['rew_jax_s']:9.3f} {r['ax_s']/max(r['rew_np_s'],1e-9):10.2f}"

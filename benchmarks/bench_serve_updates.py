@@ -361,4 +361,7 @@ def main(
 
 
 if __name__ == "__main__":
+    from repro.compat import use_compilation_cache
+
+    use_compilation_cache()
     main(out_json="BENCH_serve.json")

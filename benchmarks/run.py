@@ -3,7 +3,7 @@
   Table 2 -> bench_materialisation  (AX vs REW work/triples factors)
   Table 3 -> bench_scaling          (wall times across shard counts)
   §5      -> bench_sparql           (query answering on T vs T^rho)
-  kernels -> bench_kernels          (Pallas interpret-mode vs jnp oracle)
+  kernels -> bench_kernels          (Pallas kernels vs jnp oracle)
   updates -> bench_incremental      (host vs sharded maintenance rounds vs
                                      from-scratch; writes BENCH_incremental.json)
   serve   -> bench_serve_updates    (query latency idle vs during maintenance
@@ -304,6 +304,9 @@ def check(tolerance: float = 0.2) -> int:
 
 
 def main() -> None:
+    from repro.compat import use_compilation_cache
+
+    use_compilation_cache()
     argv = sys.argv[1:]
     if argv and argv[0] == "--check":
         tol = float(argv[1]) if len(argv) > 1 else 0.2

@@ -1,7 +1,7 @@
 """owl:sameAs rewriting as entity resolution for GNN training.
 
 The framework-level integration of the paper's technique with the assigned
-GNN architectures (DESIGN.md §4): a KG whose entities carry duplicate
+GNN architectures: a KG whose entities carry duplicate
 registrations is materialised with REW; the representative map rho then
 rewrites the GNN's edge_index (the ``rewrite_triples`` kernel's op) and
 merged nodes collapse — fewer nodes and deduplicated edges before message
@@ -80,7 +80,7 @@ def main():
         [raw["edge_index"][0], np.zeros_like(raw["edge_index"][0]), raw["edge_index"][1]],
         axis=1,
     )
-    rewritten, _changed = ops.rewrite_triples(spo, rep, interpret=True)
+    rewritten, _changed = ops.rewrite_triples(spo, rep)
     rewritten = np.asarray(rewritten)
     edges = np.unique(rewritten[:, [0, 2]], axis=0)
     dedup = dict(raw)

@@ -121,11 +121,7 @@ def audit_engine(engine, state, passes=None) -> list[Violation]:
     argsort).  ``arena_rows`` for the length thresholds is taken from the
     traced state.
     """
-    import warnings
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     from repro.core import incremental_spmd  # noqa: F401  (registers fns)
     from repro.core.engine_jax import AUDIT_REGISTRY
@@ -146,11 +142,7 @@ def audit_engine(engine, state, passes=None) -> list[Violation]:
 
 def audited_fn_labels(engine, state) -> list[str]:
     """The labels of every traced fn in the registered inventory."""
-    import warnings
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     from repro.core import incremental_spmd  # noqa: F401
     from repro.core.engine_jax import AUDIT_REGISTRY

@@ -84,11 +84,7 @@ def _trace(fn, *avals):
 
 def trace_fixture(name: str):
     """Trace a fixture by name; returns ``(label, jaxpr, arena_rows)``."""
-    import warnings
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     i64 = jnp.int64
     i32 = jnp.int32

@@ -12,12 +12,7 @@ from __future__ import annotations
 
 from dataclasses import asdict, dataclass
 
-from jax.core import Jaxpr
-
-try:  # pragma: no cover - layout differs across jax lines
-    from jax.core import ClosedJaxpr
-except ImportError:  # pragma: no cover - newer jax
-    from jax.extend.core import ClosedJaxpr
+from repro.compat import ClosedJaxpr, Jaxpr
 
 
 # ---------------------------------------------------------------------------

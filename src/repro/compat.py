@@ -1,75 +1,75 @@
-"""JAX version compatibility shims (policy: docs/incremental.md §compat).
+"""The few JAX API surfaces the repo pins in one place (jax 0.9).
 
-The repo targets the jax that ships in the container (0.4.x today) while
-staying forward-compatible with the 0.5+/0.6+ API renames.  Three surfaces
-moved between those lines:
+Everything else in the repo goes through these helpers rather than naming
+the JAX spellings inline:
 
-  * ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-    ``jax.make_mesh``) only exist on jax >= 0.5 — older meshes are implicitly
-    all-Auto, so omitting the kwarg is semantically identical,
-  * ``jax.shard_map`` (with ``check_vma=``) is the 0.6 name for
-    ``jax.experimental.shard_map.shard_map`` (with ``check_rep=``),
-  * ``Compiled.cost_analysis()`` returns a dict on new jax but a
-    single-element ``list[dict]`` on 0.4.x.
-
-Everything else in the repo must go through these helpers instead of
-feature-sniffing jax inline.
+  * :func:`enable_x64` — the 64-bit scope the packed triple keys need,
+  * :func:`make_mesh` — meshes with explicit all-Auto axis types,
+  * :func:`shard_map` — unchecked ``jax.shard_map``,
+  * :func:`xla_cost_analysis` — ``Compiled.cost_analysis()`` as a dict,
+  * :func:`use_compilation_cache` — the persistent compile cache of the
+    chip-facing entry points,
+  * ``Jaxpr`` / ``ClosedJaxpr`` — the jaxpr types the trace audit walks.
 """
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-HAS_TOPLEVEL_SHARD_MAP = hasattr(jax, "shard_map")
+__all__ = [
+    "ClosedJaxpr", "Jaxpr", "enable_x64", "make_mesh", "shard_map",
+    "use_compilation_cache", "xla_cost_analysis",
+]
+
+# a fixed path inside the checkout: a directory named afresh on each run
+# (a temporary name, a process id) would never be found again
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def axis_types_auto(n: int):
-    """``axis_types=`` value for an n-axis all-Auto mesh; None on old jax
-    (whose meshes are implicitly Auto and reject the kwarg)."""
-    if not HAS_AXIS_TYPE:
-        return None
-    return (jax.sharding.AxisType.Auto,) * n
+def enable_x64():
+    """Context manager: 64-bit dtypes on inside the ``with`` block."""
+    return jax.enable_x64(True)
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_types = axis_types_auto(len(axes))
-    if axis_types is not None:
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(shape, axes, **kwargs)
+    """``jax.make_mesh`` with explicit-Auto axis types."""
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        **kwargs,
+    )
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
-    """Unchecked shard_map across the 0.4 -> 0.6 API rename.
+    """Unchecked ``jax.shard_map``.
 
-    Replication/VMA checking is disabled on both paths: the engine's round
-    body mixes replicated and sharded outputs in ways the checker rejects
-    (the all-gather/all_to_all exchanges are hand-verified instead).
+    VMA checking is disabled: the engine's round body mixes replicated and
+    sharded outputs in ways the checker rejects (the all-gather/all_to_all
+    exchanges are hand-verified instead).
     """
-    if HAS_TOPLEVEL_SHARD_MAP:
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version.
+    """``Compiled.cost_analysis()`` as a flat dict (``{}`` when empty)."""
+    return dict(compiled.cost_analysis() or {})
 
-    jax 0.4.x returns ``[per_program_dict]``; 0.5+ returns the dict itself.
-    An empty analysis normalises to ``{}``.
+
+def use_compilation_cache() -> str:
+    """Turn on JAX's persistent compile cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins and JAX reads it itself;
+    otherwise the cache lives at :data:`DEFAULT_CACHE_DIR`.  Call before the
+    first compile.
     """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -5,7 +5,7 @@ oracle; this module is the TPU-shaped implementation: every buffer has a
 static capacity, every step is a pure jittable function, and the same round
 body runs single-device or SPMD under ``shard_map`` (pass ``mesh=``).
 
-Design (DESIGN.md §2):
+Design:
   * store  = arena ``spo (CAP,3) int32`` + ``epoch (CAP,) int32`` (-1 = free,
     else the round the fact was inserted) + ``marked (CAP,) bool`` (the
     paper's outdated bit; marked facts are skipped by matching but retained),
@@ -41,7 +41,6 @@ need 63 bits; inputs/outputs stay int32.
 
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass
 from functools import partial
 
@@ -51,7 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as compat_shard_map
+from repro.compat import enable_x64, shard_map as compat_shard_map
 
 from repro.kernels import ops as kernel_ops
 from repro.kernels.merge import merge_sorted
@@ -60,10 +59,6 @@ from .rules import Program, Rule
 from .stats import DispatchCounter, MatStats
 from .terms import DIFFERENT_FROM, SAME_AS, is_var
 from .uf import FrozenRho, compress_np, merge_pairs_jax
-
-with warnings.catch_warnings():
-    warnings.simplefilter("ignore", DeprecationWarning)
-    from jax.experimental import enable_x64
 
 I32 = jnp.int32
 # numpy scalar (not jnp): module import happens outside the enable_x64 scope
@@ -96,6 +91,19 @@ def _pack_cols(cols: list[jnp.ndarray]) -> jnp.ndarray:
     for c in cols:
         key = (key << 21) | c.astype(jnp.int64)
     return key
+
+
+def argsort_keys(keys: jnp.ndarray) -> jnp.ndarray:
+    """Stable ascending argsort of packed int64 keys, as int32 positions.
+
+    The permutation of ``jnp.argsort(keys, stable=True)``, with the position
+    as a second sort key in place of a stable sort: the TPU compiler builds
+    that in about half the time for 64-bit keys (2^19 rows: 35 s against
+    76 s, compiled for a v5e on a shared CPU host), and every engine program
+    that sorts keys pays it once per shape.
+    """
+    iota = jax.lax.broadcasted_iota(I32, keys.shape, 0)
+    return jax.lax.sort((keys, iota), num_keys=2, is_stable=False)[1]
 
 
 def _epoch_ok(
@@ -202,7 +210,7 @@ def _expand_join(
     if use_kernel:  # sort-free Pallas counting-rank dedup (same stable order)
         border = kernel_ops.dedup_order(bkey)
     else:
-        border = jnp.argsort(bkey)  # bind_cap-sized — never the arena
+        border = argsort_keys(bkey)  # bind_cap-sized — never the arena
     bkey_s = bkey[border]
     # unrolled binary search: the arena-length query side makes the scan
     # loop's per-step dispatch the dominant cost on CPU
@@ -831,8 +839,9 @@ def process_candidates(
 
     # 6) reflexivity (Algorithm 4 lines 17-18): <c, sameAs, c> for each
     # resource of each candidate, plus <sameAs,sameAs,sameAs>
-    res = all_c.reshape(-1)
-    res_valid = jnp.repeat(all_v, 3)
+    # column-major: flattening an (n, 3) block is a relayout on the TPU
+    res = jnp.concatenate([all_c[:, 0], all_c[:, 1], all_c[:, 2]])
+    res_valid = jnp.concatenate([all_v] * 3)
     refl = jnp.stack([res, jnp.full_like(res, SAME_AS), res], axis=1)
     sa_row = jnp.asarray([[SAME_AS, SAME_AS, SAME_AS]], dtype=I32)
     any_v = jnp.any(all_v)
@@ -864,7 +873,7 @@ def process_candidates(
     if use_kernel:  # sort-free Pallas counting-rank dedup (same stable order)
         order = kernel_ops.dedup_order(skeys)
     else:
-        order = jnp.argsort(skeys, stable=True)
+        order = argsort_keys(skeys)
     sk = skeys[order]
     uniq = jnp.concatenate([jnp.asarray([True]), sk[1:] != sk[:-1]])
     uniq = uniq & (sk < KEY_MAX)
@@ -1144,8 +1153,8 @@ def _rebuild_index(spo, epoch, marked):
     """Full index rebuild: the ONE allowed arena argsort (per mutation epoch)."""
     live = (epoch >= 0) & ~marked
     keys = jnp.where(live, _pack3(spo), KEY_MAX)
-    perm = jnp.argsort(keys)
-    return perm.astype(I32), keys[perm]
+    perm = argsort_keys(keys)
+    return perm, keys[perm]
 
 
 def _publish_snapshot(spo, sort_perm, sorted_keys):
@@ -1169,7 +1178,7 @@ def _publish_snapshot(spo, sort_perm, sorted_keys):
     p = tri[:, 1].astype(jnp.int64)
     o = tri[:, 2].astype(jnp.int64)
     pos_keys = jnp.where(live, (p << 42) | (o << 21) | s, KEY_MAX)
-    perm2 = jnp.argsort(pos_keys)
+    perm2 = argsort_keys(pos_keys)
     return tri, sorted_keys, tri[perm2], pos_keys[perm2], n_live
 
 

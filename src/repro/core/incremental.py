@@ -55,9 +55,8 @@ algorithm on top of the existing engine pieces:
     set — same rho, same normal-form store, same Theorem-1 expansion.
 
 Normal forms of large batches can be computed through the Pallas kernel
-:func:`repro.kernels.rewrite_triples.rewrite_triples` (``use_kernel=True``;
-interpret mode off-TPU) — the same kernel the TPU engine uses for its sweep —
-or through plain numpy gathers (the default at CPU test scale).
+:func:`repro.kernels.ops.rewrite_triples` (``use_kernel=True``; Mosaic on a
+TPU, interpreted elsewhere) or through plain numpy gathers (the default).
 """
 
 from __future__ import annotations
@@ -94,9 +93,9 @@ def normal_forms(
     if use_kernel:
         import jax.numpy as jnp
 
-        from repro.kernels.rewrite_triples import rewrite_triples
+        from repro.kernels import ops
 
-        out, _changed = rewrite_triples(
+        out, _changed = ops.rewrite_triples(
             jnp.asarray(spo, jnp.int32), jnp.asarray(rep, jnp.int32)
         )
         return np.asarray(out, dtype=np.int32)

@@ -74,6 +74,7 @@ from .engine_jax import (
     _pack3,
     _pow2,
     _route_rows,
+    argsort_keys,
     register_auditable,
 )
 from repro.kernels import ops as kernel_ops
@@ -120,6 +121,18 @@ def _psum_bool(x, axis):
     return jax.lax.psum(x.astype(I32), axis) > 0
 
 
+def _resource_mask(res, valid, n_res):
+    """Bool ``(n_res,)`` mask of the resources ``res[valid]``.
+
+    The scatter-max runs on int32: the TPU compiler lowers a bool scatter
+    through a sort of its indices, an int32 one without.
+    """
+    hits = jnp.zeros(n_res, I32).at[jnp.where(valid, res, 0)].max(
+        valid.astype(I32)
+    )
+    return hits > 0
+
+
 def _seed_tombs(sorted_keys, sort_perm, epoch, marked, tomb, q, qv, *, axis):
     """Tag wave-0 tombstones: local rows matching the replicated queries."""
     untagged = (epoch >= 0) & ~marked & (tomb < 0)
@@ -163,9 +176,9 @@ def _od_step(
     fcols, fvalid, f_ov = _engine_compact(
         {"s": spo[:, 0], "p": spo[:, 1], "o": spo[:, 2]}, frontier, refl_cap
     )
-    f_spo = jnp.stack([fcols["s"], fcols["p"], fcols["o"]], axis=1)
-    res = f_spo.reshape(-1)
-    res_v = jnp.repeat(fvalid, 3)
+    # column-major: flattening an (n, 3) block is a relayout on the TPU
+    res = jnp.concatenate([fcols["s"], fcols["p"], fcols["o"]])
+    res_v = jnp.concatenate([fvalid] * 3)
     refl = jnp.stack([res, jnp.full_like(res, SAME_AS), res], axis=1)
     sa_row = jnp.asarray([[SAME_AS] * 3], I32)
     any_f = frontier.any()
@@ -177,7 +190,7 @@ def _od_step(
     if use_kernel:  # sort-free Pallas counting-rank dedup
         order = kernel_ops.dedup_order(keys)
     else:
-        order = jnp.argsort(keys)
+        order = argsort_keys(keys)
     sk = keys[order]
     uniq = jnp.concatenate([jnp.asarray([True]), sk[1:] != sk[:-1]])
     stream, sv = stream[order], uniq & (sk < KEY_MAX)
@@ -206,9 +219,7 @@ def _od_step(
         & (spo[:, 0] == spo[:, 2])
         & (sizes[spo[:, 0]] > 1)
     )
-    cand = jnp.zeros(rep.shape[0], bool).at[
-        jnp.where(is_wit, spo[:, 0], 0)
-    ].max(is_wit)
+    cand = _resource_mask(spo[:, 0], is_wit, rep.shape[0])
     cand = _psum_bool(cand, axis)
     fresh = cand & ~suspect
     suspect = suspect | cand
@@ -227,14 +238,9 @@ def _od_step(
     # per-position resource masks of the wave's new rows: the host driver
     # skips next wave's tombstone plans whose delta atom cannot match them
     if with_masks:
-        fm = []
-        for pos in range(3):
-            fm.append(
-                jnp.zeros(rep.shape[0], bool).at[
-                    jnp.where(new, spo[:, pos], 0)
-                ].max(new)
-            )
-        od_masks = _psum_bool(jnp.stack(fm), axis)
+        od_masks = _psum_bool(jnp.stack([
+            _resource_mask(spo[:, pos], new, rep.shape[0]) for pos in range(3)
+        ]), axis)
     else:
         od_masks = jnp.zeros((3, rep.shape[0]), bool)
     return tomb, suspect, n_new[None], overflow[None], f_ov[None], od_masks
@@ -247,13 +253,9 @@ def _finalize_tombs(spo, epoch, marked, tomb, sorted_keys, sort_perm, rep, *, ax
     finalised rows leave the persistent index by a stable partition (no
     sort), keeping it exact for the rederive phase's membership probes."""
     tombed = tomb >= 0
-    masks = []
-    for pos in range(3):
-        m = jnp.zeros(rep.shape[0], bool).at[
-            jnp.where(tombed, spo[:, pos], 0)
-        ].max(tombed)
-        masks.append(m)
-    od_mask = jnp.stack(masks)  # (3, n_res)
+    od_mask = jnp.stack([
+        _resource_mask(spo[:, pos], tombed, rep.shape[0]) for pos in range(3)
+    ])  # (3, n_res)
     od_mask = _psum_bool(od_mask, axis)
     n_od = tombed.sum().astype(I32)
     if axis is not None:
@@ -299,9 +301,13 @@ def _member(sorted_keys, q, qv, *, axis):
 def _occupancy(spo, epoch, marked, rep, *, axis):
     """Replicated mask of resources occurring in live store rows."""
     live = (epoch >= 0) & ~marked
-    res = spo.reshape(-1)
-    lv = jnp.repeat(live, 3)
-    occ = jnp.zeros(rep.shape[0], bool).at[jnp.where(lv, res, 0)].max(lv)
+    # column by column: flattening the (C, 3) arena is a relayout that the
+    # TPU compiler takes about a minute to build
+    occ = (
+        _resource_mask(spo[:, 0], live, rep.shape[0])
+        | _resource_mask(spo[:, 1], live, rep.shape[0])
+        | _resource_mask(spo[:, 2], live, rep.shape[0])
+    )
     return _psum_bool(occ, axis)
 
 
@@ -626,9 +632,7 @@ def _delete_phases_tagged(engine, state, delta, max_rounds, tag):
 
     # -- backward: seed + overdelete waves (epoch-tagged tombstones) ---------
     if engine.use_kernel:
-        from repro.kernels.rewrite_triples import rewrite_owner
-
-        nf_j, owner_j = rewrite_owner(
+        nf_j, owner_j = kernel_ops.rewrite_owner(
             jnp.asarray(delta, jnp.int32),
             jnp.asarray(rep_host, jnp.int32),
             engine.n_shards,

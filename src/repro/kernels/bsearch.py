@@ -52,7 +52,7 @@ def search_bounds(
     *,
     block: int = 256,
     tile: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(lower, upper) positions of int64 ``queries`` in sorted int64 ``keys``.
 
@@ -87,7 +87,7 @@ def prefix_range_bounds(
     *,
     block: int = 256,
     tile: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(start, end) index ranges of (s, p, o)-prefix queries in sorted keys.
 

@@ -32,49 +32,55 @@ from __future__ import annotations
 
 import functools
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+# block index maps must return int32 even when traced under enable_x64,
+# where a bare Python 0 would become an int64 constant Mosaic cannot lower
+_ZERO = np.int32(0)
 
 
 def _rank_kernel(qhi_ref, qlo_ref, khi_ref, klo_ref, rank_ref, *, block, tile):
     i = pl.program_id(0)
     t = pl.program_id(1)
-    qhi = qhi_ref[...]  # (block, 1) int32: high halves, signed compare
-    khi = khi_ref[...]  # (tile, 1)
+    # queries are a (block, 1) column, keys a (1, tile) row: the compares
+    # broadcast to (block, tile) with no in-kernel relayout
+    qhi = qhi_ref[...]  # high halves, signed compare
+    khi = khi_ref[...]
     # low halves compare UNSIGNED: reinterpret the int32 bits as uint32
     qlo = qlo_ref[...].astype(jnp.uint32)
     klo = klo_ref[...].astype(jnp.uint32)
     # global element indices tie-break equal keys by position (stability)
     q_idx = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
-    k_idx = t * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
-    k_lt_q = (khi[None, :, 0] < qhi[:, :1]) | (
-        (khi[None, :, 0] == qhi[:, :1]) & (klo[None, :, 0] < qlo[:, :1])
-    )
-    k_eq_q = (khi[None, :, 0] == qhi[:, :1]) & (klo[None, :, 0] == qlo[:, :1])
-    counts = k_lt_q | (k_eq_q & (k_idx[None, :, 0] < q_idx[:, :1]))
+    k_idx = t * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+    k_lt_q = (khi < qhi) | ((khi == qhi) & (klo < qlo))
+    k_eq_q = (khi == qhi) & (klo == qlo)
+    counts = (k_lt_q | (k_eq_q & (k_idx < q_idx))).astype(jnp.int32)
 
     @pl.when(t == 0)
     def _init():
         rank_ref[...] = jnp.zeros_like(rank_ref)
 
-    rank_ref[...] += jnp.sum(counts, axis=1, keepdims=True).astype(jnp.int32)
+    rank_ref[...] += jnp.sum(counts, axis=1, keepdims=True, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "tile", "interpret"))
 def _rank_call(qhi, qlo, khi, klo, *, block, tile, interpret):
-    n_q, n_k = qhi.shape[0], khi.shape[0]
+    n_q, n_k = qhi.shape[0], khi.shape[1]
     grid = (n_q // block, n_k // tile)
     return pl.pallas_call(
         functools.partial(_rank_kernel, block=block, tile=tile),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block, 1), lambda i, t: (i, 0)),
-            pl.BlockSpec((block, 1), lambda i, t: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i, t: (t, 0)),
-            pl.BlockSpec((tile, 1), lambda i, t: (t, 0)),
+            pl.BlockSpec((block, 1), lambda i, t: (i, _ZERO)),
+            pl.BlockSpec((block, 1), lambda i, t: (i, _ZERO)),
+            pl.BlockSpec((1, tile), lambda i, t: (_ZERO, t)),
+            pl.BlockSpec((1, tile), lambda i, t: (_ZERO, t)),
         ],
-        out_specs=pl.BlockSpec((block, 1), lambda i, t: (i, 0)),
+        out_specs=pl.BlockSpec((block, 1), lambda i, t: (i, _ZERO)),
         out_shape=jax.ShapeDtypeStruct((n_q, 1), jnp.int32),
         interpret=interpret,
     )(qhi, qlo, khi, klo)
@@ -91,7 +97,7 @@ def _split_halves(keys):
     return parts[:, 1:2], parts[:, 0:1]
 
 
-def dedup_order(keys, *, block: int = 128, tile: int = 128, interpret=None):
+def dedup_order(keys, *, block: int = 128, tile: int = 128, interpret: bool):
     """Stable ascending permutation of ``keys`` ((n,) int64, non-negative).
 
     ``order = dedup_order(k)`` satisfies ``k[order] == jnp.sort(k)`` with
@@ -99,8 +105,6 @@ def dedup_order(keys, *, block: int = 128, tile: int = 128, interpret=None):
     ``jnp.argsort(keys, stable=True)`` built from counting + one
     delta-width scatter, no sort primitive.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n = keys.shape[0]
     kmax = jnp.asarray((1 << 63) - 1, keys.dtype)
     q_pad = -n % block
@@ -109,6 +113,7 @@ def dedup_order(keys, *, block: int = 128, tile: int = 128, interpret=None):
     k = jnp.concatenate([keys, jnp.full((k_pad,), kmax)]) if k_pad else keys
     qhi, qlo = _split_halves(q)
     khi, klo = _split_halves(k)
+    khi, klo = khi.reshape(1, -1), klo.reshape(1, -1)
     # key-side padding never perturbs real ranks: a pad is >= every key and
     # its tie-break index >= n, so it counts into no query slot below n
     rank = _rank_call(

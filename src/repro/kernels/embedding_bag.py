@@ -47,7 +47,7 @@ def embedding_bag(
     *,
     block: int = 128,
     tile: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """out (B, K) = sum_f table[ids[:, f]] for int32 ids (B, F)."""
     b, f = ids.shape

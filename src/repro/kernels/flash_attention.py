@@ -96,7 +96,7 @@ def flash_attention_bhsd(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = False,
+    interpret: bool,
 ) -> jnp.ndarray:
     b, h, s, d = q.shape
     kv, t = k.shape[1], k.shape[2]

@@ -28,7 +28,7 @@ def fm_interact(
     x: jnp.ndarray,
     *,
     block: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """x (B, F, K) field embeddings (already scaled by feature values) ->
     (B,) second-order FM interaction term."""

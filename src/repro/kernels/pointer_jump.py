@@ -1,6 +1,6 @@
 """Pallas TPU kernel: one pointer-doubling step ``out[i] = table[idx[i]]``.
 
-The union-find compression loop (DESIGN.md §2) is ``rep = rep[rep]`` iterated
+The union-find compression loop is ``rep = rep[rep]`` iterated
 O(log depth) times.  On TPU there is no scalar gather from HBM worth its DMA
 cost, so the gather is reformulated as **one-hot matmul over table tiles**:
 for each VMEM-resident tile ``table[t0:t0+T]``, rows whose index falls inside
@@ -50,7 +50,7 @@ def pointer_jump(
     *,
     block: int = 512,
     tile: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """table[idx] for int32 1-D ``idx`` and ``table`` (padded to block/tile)."""
     n = idx.shape[0]

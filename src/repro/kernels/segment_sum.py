@@ -47,7 +47,7 @@ def segment_sum(
     *,
     block: int = 512,
     stile: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """(N, K) values + (N,) int32 segment ids -> (n_segments, K) sums."""
     n, k = x.shape

@@ -10,19 +10,18 @@ Axes:
   * ``data``  — batch / edge / row sharding (ICI),
   * ``model`` — tensor/expert/vocab/embedding-row parallelism (ICI).
 
-``axis_types_auto`` / ``make_mesh`` are re-exported from :mod:`repro.compat`
-so callers that build their own meshes stay portable across the jax 0.4/0.6
-``AxisType`` rename without feature-sniffing jax themselves.
+``make_mesh`` is re-exported from :mod:`repro.compat` for callers that build
+their own meshes.
 """
 
 from __future__ import annotations
 
 import jax
 
-from repro.compat import axis_types_auto, make_mesh
+from repro.compat import make_mesh
 
 __all__ = [
-    "axis_types_auto", "make_mesh", "make_production_mesh",
+    "make_mesh", "make_production_mesh",
     "make_engine_mesh", "mesh_size", "data_axes", "model_axis",
 ]
 

@@ -62,12 +62,12 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro.compat import enable_x64
 from repro.core.engine_jax import (
     CapacityError,
     EngineState,
     JaxEngine,
     StoreSnapshot,
-    enable_x64,
 )
 from repro.core.incremental_spmd import spmd_add_phases, spmd_delete_phases
 from repro.core.rules import Program
@@ -248,6 +248,12 @@ class TripleStore:
             },
             "compiles_by_family": dict(d.compiles),
         }
+
+    @property
+    def query_stats(self) -> dict:
+        """The batched executor's counters (``batched`` / ``fallback`` /
+        ``overflow`` / ``groups``); empty when batching is off."""
+        return dict(self._batched.stats) if self._batched is not None else {}
 
     def audit(self) -> list[str]:
         """Cross-check this store's observed dispatches against the static

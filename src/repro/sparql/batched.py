@@ -36,7 +36,6 @@ registers with the trace-audit inventory as the ``"bgp"`` family.
 
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass
 from functools import partial
 
@@ -45,16 +44,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compat import enable_x64
 from repro.core.engine_jax import I32, register_auditable
 from repro.core.seminaive import Bindings
 from repro.core.terms import is_var
 
 from .algebra import Query
 from .executor import _Solutions, _finish, _normalise_query, evaluate_at
-
-with warnings.catch_warnings():
-    warnings.simplefilter("ignore", DeprecationWarning)
-    from jax.experimental import enable_x64
 
 _MAXID = (1 << 21) - 1
 

@@ -119,6 +119,7 @@ class Trainer:
     def run(self, until: int | None = None):
         until = until if until is not None else self.cfg.n_steps
         ewma = None
+        first = self.step
         while self.step < until:
             t0 = time.time()
             batch = self.batch_fn(self.step)
@@ -136,14 +137,16 @@ class Trainer:
             self.losses.append(loss)
             dt = time.time() - t0
 
-            # straggler detection (EWMA of step time)
+            # straggler detection (EWMA of step time); the run's first step
+            # compiles, so it does not seed the average
             if ewma is None:
-                ewma = dt
-            if dt > self.cfg.straggler_factor * ewma and self.step > 2:
+                ewma = dt if self.step > first else None
+            elif dt > self.cfg.straggler_factor * ewma and self.step > 2:
                 self.straggler_events.append((self.step, dt))
                 if self.on_straggler:
                     self.on_straggler(self.step, dt)
-            ewma = 0.9 * ewma + 0.1 * dt
+            if ewma is not None:
+                ewma = 0.9 * ewma + 0.1 * dt
 
             # heartbeat for the external supervisor
             if self.cfg.heartbeat_path:

@@ -119,11 +119,7 @@ def test_dtype_safety_allows_widening_and_untainted_casts():
     a key further, or narrowing a value that never saw a pack, is fine."""
     import jax
     import jax.numpy as jnp
-    import warnings
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     def benign(s, x):
         key = (s.astype(jnp.int64) << jnp.int64(21)) | s.astype(jnp.int64)

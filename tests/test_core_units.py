@@ -213,7 +213,8 @@ def test_probe_boundary_key_no_sentinel_alias():
     tombstoned by _seed_tombs)."""
     import jax.numpy as jnp
 
-    from repro.core.engine_jax import I32, KEY_MAX, enable_x64
+    from repro.compat import enable_x64
+    from repro.core.engine_jax import I32, KEY_MAX
     from repro.core.incremental_spmd import _probe_index
 
     m = (1 << 21) - 1
@@ -241,7 +242,8 @@ def test_probe_respects_select_mask():
     tombstoned) reports no hit."""
     import jax.numpy as jnp
 
-    from repro.core.engine_jax import I32, KEY_MAX, enable_x64
+    from repro.compat import enable_x64
+    from repro.core.engine_jax import I32, KEY_MAX
     from repro.core.incremental_spmd import _probe_index
 
     with enable_x64():
@@ -262,7 +264,8 @@ def test_compact_is_stable_partition():
     flags valid rows beyond cap; tail rows stay masked."""
     import jax.numpy as jnp
 
-    from repro.core.engine_jax import _compact, enable_x64
+    from repro.compat import enable_x64
+    from repro.core.engine_jax import _compact
 
     with enable_x64():
         col = jnp.asarray(np.arange(10, dtype=np.int32))
@@ -287,7 +290,7 @@ def test_index_invariant_report_catches_corruption():
     assert index_invariant_report(state) == []
     import jax.numpy as jnp
 
-    from repro.core.engine_jax import enable_x64
+    from repro.compat import enable_x64
 
     with enable_x64():
         state.sorted_keys = state.sorted_keys.at[0].set(jnp.int64(12345))
@@ -336,7 +339,8 @@ def test_member_rejects_key_max_padding_match():
     _member must not report it present by matching index padding."""
     import jax.numpy as jnp
 
-    from repro.core.engine_jax import I32, enable_x64
+    from repro.compat import enable_x64
+    from repro.core.engine_jax import I32
     from repro.core.incremental_spmd import _member
 
     m = (1 << 21) - 1

@@ -11,7 +11,7 @@ from repro.core.materialise import Contradiction, materialise
 from repro.core.rules import Program, Rule
 from repro.core.terms import DIFFERENT_FROM, SAME_AS
 from repro.core.triples import pack
-from repro.data.datasets import pex, pex_rule_rewrite, single_clique
+from repro.data.datasets import clique_with_spokes, pex, pex_rule_rewrite, single_clique
 
 
 def _sets_equal(a: np.ndarray, b: np.ndarray) -> bool:
@@ -36,12 +36,15 @@ def test_jax_engine_matches_reference(ds):
 
 def test_capacity_growth_retry():
     """Tiny initial capacities must transparently grow, not fail."""
-    facts, prog, dic = single_clique(6)
+    # 14 explicit facts whose REW store (27 rows) cannot fit a 4-row arena
+    facts, prog, dic = clique_with_spokes(3, 12)
     eng = JaxEngine(dic.n_resources, capacity=4, bind_cap=4, out_cap=4, rewrite_cap=4)
     spo, rep, stats = eng.materialise(facts, prog)
     ref = materialise(facts, prog, dic.n_resources, mode="REW")
+    assert spo.shape[0] > 4
     assert _sets_equal(ref.triples(), spo)
-    assert eng.capacity > 4  # growth happened
+    assert (rep == ref.rep).all()
+    assert eng.capacity > 4  # the arena grew
 
 
 def test_contradiction_raised():

@@ -106,11 +106,7 @@ def test_fused_with_dedup_kernel_matches_scratch():
 # ---------------------------------------------------------------------------
 
 def _traced(engine, state, name):
-    import warnings
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     from repro.core import incremental_spmd  # noqa: F401 (registers fns)
     from repro.core.engine_jax import AUDIT_REGISTRY

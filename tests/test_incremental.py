@@ -321,23 +321,29 @@ if HAVE_HYPOTHESIS:
         suppress_health_check=[HealthCheck.too_slow, HealthCheck.data_too_large],
     )
 
-    def _fuzz_body(seed, n_events, batch, n_groups, group_size, n_plain,
-                   hierarchy_depth):
-        gen_kw = dict(
-            n_groups=n_groups, group_size=group_size, n_spokes_per=1,
-            n_plain=n_plain, hierarchy_depth=hierarchy_depth,
+    def _fuzz_test(max_examples):
+        """A fresh test function per budget: hypothesis applies ``settings``
+        once per function object."""
+
+        def fuzz(seed, n_events, batch, n_groups, group_size, n_plain,
+                 hierarchy_depth):
+            gen_kw = dict(
+                n_groups=n_groups, group_size=group_size, n_spokes_per=1,
+                n_plain=n_plain, hierarchy_depth=hierarchy_depth,
+            )
+            _run_differential_stream(gen_kw, seed, n_events, batch, engine=True)
+
+        return _stream_params(
+            settings(max_examples=max_examples, **_fuzz_settings)(fuzz)
         )
-        _run_differential_stream(gen_kw, seed, n_events, batch, engine=True)
 
     # quick budget for tier-1; hypothesis shrinks a failing case to a
     # minimal stream (fewest events, smallest batches, tiniest graph)
-    test_fuzz_update_stream_differential = _stream_params(
-        settings(max_examples=10, **_fuzz_settings)(_fuzz_body)
-    )
+    test_fuzz_update_stream_differential = _fuzz_test(10)
 
     # nightly tier: larger example budget, deselectable via -m "not slow"
     test_fuzz_update_stream_differential_nightly = pytest.mark.slow(
-        _stream_params(settings(max_examples=100, **_fuzz_settings)(_fuzz_body))
+        _fuzz_test(100)
     )
 
 
@@ -361,7 +367,7 @@ def test_normal_forms_kernel_parity():
 def test_rewrite_owner_kernel_parity():
     """Fused (normal form, owner shard) matches the numpy route keys."""
     from repro.core.uf import compress_np
-    from repro.kernels.rewrite_triples import rewrite_owner
+    from repro.kernels.ops import rewrite_owner
 
     rng = np.random.default_rng(1)
     rep = np.arange(300, dtype=np.int32)

@@ -56,7 +56,7 @@ def _assert_clean(eng, state, where=""):
     ids=["chain", "clique", "dbpedia_like"],
 )
 def test_index_invariant_after_every_phase(ds):
-    from repro.core.engine_jax import enable_x64
+    from repro.compat import enable_x64
 
     facts, prog, dic = ds()
     eng = _engine(dic)
@@ -148,7 +148,7 @@ def test_no_arena_sort_in_round_fns():
     allowed arena argsort lives in the explicit rebuild fn."""
     import jax
 
-    from repro.core.engine_jax import enable_x64
+    from repro.compat import enable_x64
     from repro.data.datasets import pex
 
     facts, prog, dic = pex()

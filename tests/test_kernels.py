@@ -81,7 +81,7 @@ def test_dedup_order_sweep(n, block, tile, dup):
     Unlike the other int64 kernels, dedup_order is called INSIDE traced
     engine code (the fused round loop), so it takes traced int64 keys under
     the engine's x64 scope — the test mirrors that calling convention."""
-    from repro.core.engine_jax import enable_x64
+    from repro.compat import enable_x64
 
     keys = RNG.integers(0, 1 << 62, n).astype(np.int64)
     n_dup = int(n * dup)
@@ -224,7 +224,7 @@ def test_flash_matches_chunked_xla_path():
 def test_merge_sorted_sweep(na, nb, dup):
     """Rank-merge of two sorted key/value columns == numpy mergesort, with
     sentinel padding and (cross-column) duplicate keys."""
-    from jax.experimental import enable_x64
+    from repro.compat import enable_x64
 
     from repro.kernels.merge import merge_ranks, merge_sorted
 

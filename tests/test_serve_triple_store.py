@@ -345,24 +345,28 @@ if HAVE_HYPOTHESIS:
         suppress_health_check=[HealthCheck.too_slow, HealthCheck.data_too_large],
     )
 
-    def _fuzz_body(seed, ticks_seed, n_events, batch, n_groups, group_size,
-                   n_plain):
-        gen_kw = dict(
-            n_groups=n_groups, group_size=group_size, n_spokes_per=1,
-            n_plain=n_plain, hierarchy_depth=1,
+    def _fuzz_test(max_examples):
+        """A fresh test function per budget: hypothesis applies ``settings``
+        once per function object."""
+
+        def fuzz(seed, ticks_seed, n_events, batch, n_groups, group_size,
+                 n_plain):
+            gen_kw = dict(
+                n_groups=n_groups, group_size=group_size, n_spokes_per=1,
+                n_plain=n_plain, hierarchy_depth=1,
+            )
+            _run_trace(gen_kw, seed, n_events, batch, ticks_seed)
+
+        return _sched_params(
+            settings(max_examples=max_examples, **_fuzz_settings)(fuzz)
         )
-        _run_trace(gen_kw, seed, n_events, batch, ticks_seed)
 
     # quick budget for tier-1; hypothesis shrinks failures to a minimal
     # schedule (fewest events, smallest graph, simplest tick pattern)
-    test_fuzz_interleaved_schedules = _sched_params(
-        settings(max_examples=5, **_fuzz_settings)(_fuzz_body)
-    )
+    test_fuzz_interleaved_schedules = _fuzz_test(5)
 
     # nightly tier: larger example budget, deselectable via -m "not slow"
-    test_fuzz_interleaved_schedules_nightly = pytest.mark.slow(
-        _sched_params(settings(max_examples=50, **_fuzz_settings)(_fuzz_body))
-    )
+    test_fuzz_interleaved_schedules_nightly = pytest.mark.slow(_fuzz_test(50))
 
 
 # ---------------------------------------------------------------------------
