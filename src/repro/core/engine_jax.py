@@ -1345,14 +1345,18 @@ class JaxEngine:
         return cls(mesh=mesh, axis=axis, **kw)
 
     # -- jit wrappers -------------------------------------------------------
-    def _wrap(self, fn, in_specs, out_specs):
-        if self.mesh is None:
-            return jax.jit(fn)
-        return jax.jit(
-            compat_shard_map(
+    def _jit_fn(self, key, fn, in_specs, out_specs) -> "_CountedFn":
+        """Jit ``fn`` (under ``shard_map`` on a mesh) and install it under
+        ``key``.  The program is named after the key's family, so a device
+        trace reads ``jit_<family>`` (``jit_fwave``, ``jit_plan``) where an
+        unnamed ``functools.partial`` would read ``jit__unknown``."""
+        if self.mesh is not None:
+            fn = compat_shard_map(
                 fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             )
-        )
+        named = partial(fn)
+        named.__name__ = _key_family(key)
+        return self._register_fn(key, jax.jit(named))
 
     def _register_fn(self, key, fn) -> "_CountedFn":
         """Install a compiled fn in the cache under dispatch accounting.
@@ -1390,11 +1394,11 @@ class JaxEngine:
             )
             d = P(a) if a else None
             rpl = P() if a else None
-            self._register_fn(plan_key, self._wrap(
-                fn,
+            self._jit_fn(
+                plan_key, fn,
                 in_specs=(d, d, d, d, d, d, rpl, rpl, rpl),
                 out_specs=(d, d, d, d, d, d),
-            ))
+            )
         return self._fns[plan_key]
 
     def _get_squeeze_fn(self, n_rows: int, target: int):
@@ -1413,9 +1417,7 @@ class JaxEngine:
             a = self.axis
             fn = partial(_squeeze_stream, target=target)
             d = P(a) if a else None
-            self._register_fn(
-                key, self._wrap(fn, in_specs=(d, d), out_specs=(d, d, d))
-            )
+            self._jit_fn(key, fn, in_specs=(d, d), out_specs=(d, d, d))
         return self._fns[key]
 
     def _get_process_fn(self, n_cand_rows: int):
@@ -1452,11 +1454,11 @@ class JaxEngine:
                 "delta_rows": d,
                 "delta_valid": d,
             }
-            self._register_fn(key, self._wrap(
-                fn,
+            self._jit_fn(
+                key, fn,
                 in_specs=(d, d, d, d, rpl, d, d, d, d, rpl),
                 out_specs=(d, d, d, d, rpl, d, d, flag_specs),
-            ))
+            )
         return self._fns[key]
 
     # -- state lifecycle -----------------------------------------------------
@@ -1762,9 +1764,8 @@ class JaxEngine:
         if key not in self._fns:
             a = self.axis
             d = P(a) if a else None
-            self._register_fn(
-                key,
-                self._wrap(_rebuild_index, in_specs=(d, d, d), out_specs=(d, d)),
+            self._jit_fn(
+                key, _rebuild_index, in_specs=(d, d, d), out_specs=(d, d),
             )
         state.sort_perm, state.sorted_keys = self._fns[key](
             state.spo, state.epoch, state.marked
@@ -1856,20 +1857,17 @@ class JaxEngine:
         incremental :meth:`~repro.core.uf.FrozenRho.refreshed` rho refresh:
         epochs that touched no clique reuse the entire expansion table.
 
-        Dispatches are tagged under the ``"publish"`` phase (an index
-        rebuild may ride along when the arena was re-laid-out this epoch).
-        Falls back to the host path under SPMD: per-shard sorted blocks
-        are not a globally sorted view, and the serving store is a
-        single-controller tier.
+        Runs in the ``"publish"`` phase (an index rebuild may ride along
+        when the arena was re-laid-out this epoch).  Falls back to the host
+        path under SPMD: per-shard sorted blocks are not a globally sorted
+        view, and the serving store is a single-controller tier.
         """
-        if self.n_shards != 1:
-            snap = self.read_snapshot(state)
-            if prev is not None:
-                snap.rho = prev.rho.refreshed(np.asarray(state.rep))
-            return snap
-        prev_phase = self.dispatches.phase
-        self.dispatches.phase = "publish"
-        try:
+        with self.dispatches.in_phase("publish"):
+            if self.n_shards != 1:
+                snap = self.read_snapshot(state)
+                if prev is not None:
+                    snap.rho = prev.rho.refreshed(np.asarray(state.rep))
+                return snap
             with enable_x64():
                 self._ensure_index(state)
                 key = ("snapshot", int(state.spo.shape[0]))
@@ -1878,17 +1876,15 @@ class JaxEngine:
                 tri, keys, tri_pos, keys_pos, n_live = self._fns[key](
                     state.spo, state.sort_perm, state.sorted_keys
                 )
-        finally:
-            self.dispatches.phase = prev_phase
-        rep_host = np.asarray(state.rep)
-        rho = prev.rho.refreshed(rep_host) if prev is not None \
-            else FrozenRho(rep_host)
-        n_live = int(n_live)
-        state.stats.triples_unmarked = n_live
-        return StoreSnapshot(
-            state.update_epoch, rho,
-            device=(tri, keys, tri_pos, keys_pos, n_live),
-        )
+            rep_host = np.asarray(state.rep)
+            rho = prev.rho.refreshed(rep_host) if prev is not None \
+                else FrozenRho(rep_host)
+            n_live = int(n_live)
+            state.stats.triples_unmarked = n_live
+            return StoreSnapshot(
+                state.update_epoch, rho,
+                device=(tri, keys, tri_pos, keys_pos, n_live),
+            )
 
     def _recover_capacity(
         self, state: EngineState, snap: dict, err: CapacityError
@@ -1902,13 +1898,13 @@ class JaxEngine:
         # fired — attribute them to a distinct "retry" phase the static
         # dispatch profile admits; the restarted generator re-tags its own
         # phases from the top
-        self.dispatches.phase = "retry"
-        self._restore(state, snap)
-        old_cap = self.capacity
-        kind = str(err)
-        self._grow_for(kind)
-        if self.capacity != old_cap:
-            self._grow_state_arena(state, old_cap)
+        with self.dispatches.in_phase("retry"):
+            self._restore(state, snap)
+            old_cap = self.capacity
+            kind = str(err)
+            self._grow_for(kind)
+            if self.capacity != old_cap:
+                self._grow_state_arena(state, old_cap)
         # restart bookkeeping (BENCH_incremental records these per profile):
         # every retry rolls the operation back; growing a WIDE cap
         # additionally recompiles every fn keyed on the outgrown width —
@@ -1921,8 +1917,9 @@ class JaxEngine:
         """The epoch barrier: an update operation's fixpoint is complete.
         No-op updates cross it too — their fixpoint is the unchanged store,
         and readers' epochs must stay monotone and attributable."""
-        state.update_epoch += 1
-        self._refresh_stats(state)
+        with self.dispatches.in_phase("barrier"):
+            state.update_epoch += 1
+            self._refresh_stats(state)
 
     def _rewrite_program(self, state: EngineState, stats):
         """Rewrite the program under the compressed current rho and classify
@@ -2180,14 +2177,14 @@ class JaxEngine:
                 "ov_squeeze": rpl,
                 "contradiction": rpl, "consts_changed": rpl,
             }
-            self._register_fn(key, self._wrap(
-                fn,
+            self._jit_fn(
+                key, fn,
                 in_specs=(
                     d, d, d, d, d, rpl, d, d, d, d,
                     rpl, rpl, rpl, rpl, rpl, rpl,
                 ),
                 out_specs=(d, d, d, d, rpl, d, d, d, d, flag_specs),
-            ))
+            )
         return self._fns[key]
 
     def _fused_forward(self, state: EngineState, cands, cand_valid,
@@ -2382,11 +2379,11 @@ class JaxEngine:
             )
             d = P(a) if a else None
             rpl = P() if a else None
-            self._register_fn(key, self._wrap(
-                fn,
+            self._jit_fn(
+                key, fn,
                 in_specs=(d, d, d, d, d, d, rpl, rpl, rpl, rpl),
                 out_specs=(d, d, d, d, d),
-            ))
+            )
         return self._fns[key]
 
     def _eval_rule_rederive(self, state: EngineState, k: int, rule: Rule, seeds):

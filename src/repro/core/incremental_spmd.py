@@ -93,7 +93,7 @@ __all__ = [
 
 
 # ---------------------------------------------------------------------------
-# per-shard step functions (pure; run under shard_map via engine._wrap)
+# per-shard step functions (pure; run under shard_map via engine._jit_fn)
 # ---------------------------------------------------------------------------
 
 def _probe_index(sorted_keys, sort_perm, select, queries, qvalid):
@@ -326,9 +326,10 @@ def _get_step_fn(engine, name, fn, in_specs, out_specs, **static):
     )
     if key not in engine._fns:
         a = engine.axis
-        engine._register_fn(key, engine._wrap(
-            partial(fn, axis=a, **static), in_specs=in_specs, out_specs=out_specs
-        ))
+        engine._jit_fn(
+            key, partial(fn, axis=a, **static),
+            in_specs=in_specs, out_specs=out_specs,
+        )
     return engine._fns[key]
 
 
@@ -394,11 +395,11 @@ def _fwave_fn(engine, plans_sig: tuple):
                 "ov_route", "ov_refl", "ov_bind", "ov_out", "ov_squeeze",
             )
         }
-        engine._register_fn(key, engine._wrap(
-            fn,
+        engine._jit_fn(
+            key, fn,
             in_specs=(d, d, d, d, d, d, rpl, rpl, rpl, rpl, rpl, rpl),
             out_specs=(d, rpl, flag_specs),
-        ))
+        )
     return engine._fns[key]
 
 
@@ -559,8 +560,7 @@ def spmd_add_phases(engine, state: EngineState, delta, max_rounds: int):
     A no-effect delta yields nothing.
     """
     tag = engine.dispatches
-    try:
-        tag.phase = "add:prepare"
+    with tag.in_phase("add:prepare"):
         engine._ensure_index(state)  # rebuild only after a capacity re-layout
         delta = dedup_rows(delta)
         delta = setdiff_rows(delta, state.explicit)
@@ -576,10 +576,8 @@ def spmd_add_phases(engine, state: EngineState, delta, max_rounds: int):
         engine._presize_delta(delta.shape[0])  # known admitted-batch cardinality
         cands, cand_valid = engine._pad_cands(delta)
         yield "prepared"
-        tag.phase = "add:forward"
+    with tag.in_phase("add:forward"):
         engine._forward(state, cands, cand_valid, [], max_rounds)
-    finally:
-        tag.phase = None
 
 
 def spmd_add_facts(engine, state: EngineState, delta, max_rounds: int) -> EngineState:
@@ -609,225 +607,222 @@ def spmd_delete_phases(engine, state: EngineState, delta, max_rounds: int):
     no-effect delta yields nothing.
     """
     tag = engine.dispatches
-    try:
-        yield from _delete_phases_tagged(engine, state, delta, max_rounds, tag)
-    finally:
-        tag.phase = None
+    with tag.in_phase("delete:prepare"):
+        engine._ensure_index(state)  # rebuild only after a capacity re-layout
+        delta = dedup_rows(delta)
+        if delta.shape[0] and state.explicit.shape[0]:
+            delta = delta[np.isin(pack(delta), pack(state.explicit))]
+        else:
+            delta = np.zeros((0, 3), np.int32)
+        if delta.shape[0] == 0:
+            return
 
+        explicit_new = setdiff_rows(state.explicit, delta)
+        rep_host = np.asarray(state.rep)
+        sizes = clique_sizes(rep_host)
 
-def _delete_phases_tagged(engine, state, delta, max_rounds, tag):
-    tag.phase = "delete:prepare"
-    engine._ensure_index(state)  # rebuild only after a capacity re-layout
-    delta = dedup_rows(delta)
-    if delta.shape[0] and state.explicit.shape[0]:
-        delta = delta[np.isin(pack(delta), pack(state.explicit))]
-    else:
-        delta = np.zeros((0, 3), np.int32)
-    if delta.shape[0] == 0:
-        return
+        # -- backward: seed + overdelete waves (epoch-tagged tombstones) -----
+        if engine.use_kernel:
+            nf_j, owner_j = kernel_ops.rewrite_owner(
+                jnp.asarray(delta, jnp.int32),
+                jnp.asarray(rep_host, jnp.int32),
+                engine.n_shards,
+            )
+            nf, owner = np.asarray(nf_j), np.asarray(owner_j)
+        else:
+            nf = rep_host[delta].astype(np.int32)
+            owner = nf[:, 0] % engine.n_shards
+        # owner-sorted queries: each shard's matches land in contiguous runs
+        nf = dedup_rows(nf[np.argsort(owner, kind="stable")])
 
-    explicit_new = setdiff_rows(state.explicit, delta)
-    rep_host = np.asarray(state.rep)
-    sizes = clique_sizes(rep_host)
+    with tag.in_phase("delete:seed"):
+        n_od_host = _seed_query(engine, state, nf)
+        yield "seeded"
 
-    # -- backward: seed + overdelete waves (epoch-tagged tombstones) ---------
-    if engine.use_kernel:
-        nf_j, owner_j = kernel_ops.rewrite_owner(
-            jnp.asarray(delta, jnp.int32),
-            jnp.asarray(rep_host, jnp.int32),
-            engine.n_shards,
-        )
-        nf, owner = np.asarray(nf_j), np.asarray(owner_j)
-    else:
-        nf = rep_host[delta].astype(np.int32)
-        owner = nf[:, 0] % engine.n_shards
-    # owner-sorted queries: each shard's matches land in contiguous runs
-    nf = dedup_rows(nf[np.argsort(owner, kind="stable")])
-    tag.phase = "delete:seed"
-    n_od_host = _seed_query(engine, state, nf)
-    yield "seeded"
-    tag.phase = "delete:wave"
+    with tag.in_phase("delete:wave"):
+        # wave-1 frontier masks come from the seed normal forms themselves
+        masks = np.zeros((3, state.n_res), dtype=bool)
+        for pos in range(3):
+            masks[pos][nf[:, pos]] = True
 
-    # wave-1 frontier masks come from the seed normal forms themselves
-    masks = np.zeros((3, state.n_res), dtype=bool)
-    for pos in range(3):
-        masks[pos][nf[:, pos]] = True
+        suspect = jnp.zeros((state.n_res,), bool)
+        sizes_j = jnp.asarray(sizes, dtype=I32)
+        if engine.fuse_rounds:
+            # one compiled fixpoint over every wave: tombstone plans + od step
+            # run in a single lax.while_loop, convergence decided on device.
+            # The host's dead-plan mask filtering is dropped (impossible plans
+            # match zero rows inside the trace) — what it saved in compute it
+            # cost in per-wave dispatches, the quantity this path exists to kill.
+            from .fused import forward_plan_signature, program_tables
 
-    suspect = jnp.zeros((state.n_res,), bool)
-    sizes_j = jnp.asarray(sizes, dtype=I32)
-    if engine.fuse_rounds:
-        # one compiled fixpoint over every wave: tombstone plans + od step
-        # run in a single lax.while_loop, convergence decided on device.
-        # The host's dead-plan mask filtering is dropped (impossible plans
-        # match zero rows inside the trace) — what it saved in compute it
-        # cost in per-wave dispatches, the quantity this path exists to kill.
-        from .fused import forward_plan_signature, program_tables
-
-        plans_sig = forward_plan_signature(state.program, tombstone=True)
-        fn = _fwave_fn(engine, plans_sig)
-        ac, hc, _cv, _cvd = program_tables(state.program)
-        state.tomb, suspect, fl = fn(
-            state.spo, state.epoch, state.marked, state.tomb,
-            state.sorted_keys, state.sort_perm, state.rep, sizes_j, suspect,
-            jnp.asarray(max_rounds, I32), ac, hc,
-        )
-
-        def _flag(name: str) -> bool:
-            return bool(np.asarray(fl[name]).reshape(-1)[0])
-
-        state.stats.od_waves += int(np.asarray(fl["iters"]).reshape(-1)[0])
-        if _flag("ov_route"):
-            raise CapacityError("route")
-        if _flag("ov_bind"):
-            raise CapacityError(engine._active_bind_kind)
-        if _flag("ov_refl") or _flag("ov_out") or _flag("ov_squeeze"):
-            # the reflexivity buffer and the plan-output stream are both
-            # sized by the ACTIVE delta width — under the wide-buffer
-            # fallback that is out_cap, whose growth kind must be named or
-            # the (clamped) delta cap would stop growing and the retry loop
-            # would spin on the same overflow
-            raise CapacityError(engine._active_delta_kind)
-        if int(np.asarray(fl["n_new"]).reshape(-1)[0]) > 0:
-            raise RuntimeError("did not converge")
-        n_wave_total = int(np.asarray(fl["n_od"]).reshape(-1)[0])
-        n_od_host += n_wave_total
-        if n_wave_total:
-            yield "wave"
-    else:
-        w = 0
-        while True:
-            w += 1
-            state.stats.od_waves += 1
-            heads, hv = _tomb_heads(engine, state, w, masks)
-            fn = _od_fn(engine, int(heads.shape[0]))
-            state.tomb, suspect, n_new, ov_route, ov_refl, od_masks = fn(
+            plans_sig = forward_plan_signature(state.program, tombstone=True)
+            fn = _fwave_fn(engine, plans_sig)
+            ac, hc, _cv, _cvd = program_tables(state.program)
+            state.tomb, suspect, fl = fn(
                 state.spo, state.epoch, state.marked, state.tomb,
-                state.sorted_keys, state.sort_perm,
-                state.rep, sizes_j, suspect, heads, hv, jnp.asarray(w, I32),
+                state.sorted_keys, state.sort_perm, state.rep, sizes_j, suspect,
+                jnp.asarray(max_rounds, I32), ac, hc,
             )
-            if bool(np.asarray(ov_route).any()):
+
+            def _flag(name: str) -> bool:
+                return bool(np.asarray(fl[name]).reshape(-1)[0])
+
+            state.stats.od_waves += int(np.asarray(fl["iters"]).reshape(-1)[0])
+            if _flag("ov_route"):
                 raise CapacityError("route")
-            if bool(np.asarray(ov_refl).any()):
-                # the reflexivity buffer is sized by the ACTIVE delta width —
-                # under the wide-buffer fallback that is out_cap, whose
-                # growth kind must be named or the (clamped) delta cap would
-                # stop growing and the retry loop would spin on the same
-                # overflow
+            if _flag("ov_bind"):
+                raise CapacityError(engine._active_bind_kind)
+            if _flag("ov_refl") or _flag("ov_out") or _flag("ov_squeeze"):
+                # the reflexivity buffer and the plan-output stream are both
+                # sized by the ACTIVE delta width — under the wide-buffer
+                # fallback that is out_cap, whose growth kind must be named or
+                # the (clamped) delta cap would stop growing and the retry loop
+                # would spin on the same overflow
                 raise CapacityError(engine._active_delta_kind)
-            n_wave = int(np.asarray(n_new).reshape(-1)[0])
-            if n_wave == 0:
-                break
-            n_od_host += n_wave
-            masks = np.asarray(od_masks)
-            yield "wave"
+            if int(np.asarray(fl["n_new"]).reshape(-1)[0]) > 0:
+                raise RuntimeError("did not converge")
+            n_wave_total = int(np.asarray(fl["n_od"]).reshape(-1)[0])
+            n_od_host += n_wave_total
+            if n_wave_total:
+                yield "wave"
+        else:
+            w = 0
+            while True:
+                w += 1
+                state.stats.od_waves += 1
+                heads, hv = _tomb_heads(engine, state, w, masks)
+                fn = _od_fn(engine, int(heads.shape[0]))
+                state.tomb, suspect, n_new, ov_route, ov_refl, od_masks = fn(
+                    state.spo, state.epoch, state.marked, state.tomb,
+                    state.sorted_keys, state.sort_perm,
+                    state.rep, sizes_j, suspect, heads, hv, jnp.asarray(w, I32),
+                )
+                if bool(np.asarray(ov_route).any()):
+                    raise CapacityError("route")
+                if bool(np.asarray(ov_refl).any()):
+                    # the reflexivity buffer is sized by the ACTIVE delta width —
+                    # under the wide-buffer fallback that is out_cap, whose
+                    # growth kind must be named or the (clamped) delta cap would
+                    # stop growing and the retry loop would spin on the same
+                    # overflow
+                    raise CapacityError(engine._active_delta_kind)
+                n_wave = int(np.asarray(n_new).reshape(-1)[0])
+                if n_wave == 0:
+                    break
+                n_od_host += n_wave
+                masks = np.asarray(od_masks)
+                yield "wave"
 
-    tag.phase = "delete:finalize"
-    # pre-size the delta buffers from the now-known overdelete cardinality:
-    # the rederive seeds and the restored candidate stream scale with it,
-    # and discovering that width by overflow restarts mid-stream is the
-    # direct mechanism behind the uobm_like steady-event regression
-    engine._presize_delta(max(n_od_host, delta.shape[0]))
+    with tag.in_phase("delete:finalize"):
+        # pre-size the delta buffers from the now-known overdelete cardinality:
+        # the rederive seeds and the restored candidate stream scale with it,
+        # and discovering that width by overflow restarts mid-stream is the
+        # direct mechanism behind the uobm_like steady-event regression
+        engine._presize_delta(max(n_od_host, delta.shape[0]))
 
-    # grab the overdeleted rows for the head-bound rederive joins while the
-    # tombstone column still identifies them (finalize resets it to -1)
-    od_rows = np.zeros((0, 3), np.int32)
-    if n_od_host and engine.rederive_mode == "targeted":
-        rows, rv, ov = _extract_fn(engine, _pow2(n_od_host))(
-            state.spo, state.tomb
-        )
-        if bool(np.asarray(ov).any()):
-            # the extract buffer is sized from the host's running count, so
-            # overflow means the count itself is wrong — an invariant
-            # violation no capacity growth can fix; surfacing it as a
-            # CapacityError would spin the retry loop growing unrelated
-            # caps against the same miscount forever
-            raise RuntimeError(
-                "overdelete extraction overflowed its host-counted bound "
-                f"({n_od_host} rows) — tombstone accounting is inconsistent"
+        # grab the overdeleted rows for the head-bound rederive joins while the
+        # tombstone column still identifies them (finalize resets it to -1)
+        od_rows = np.zeros((0, 3), np.int32)
+        if n_od_host and engine.rederive_mode == "targeted":
+            rows, rv, ov = _extract_fn(engine, _pow2(n_od_host))(
+                state.spo, state.tomb
             )
-        od_rows = np.asarray(rows).reshape(-1, 3)[np.asarray(rv).reshape(-1)]
+            if bool(np.asarray(ov).any()):
+                # the extract buffer is sized from the host's running count, so
+                # overflow means the count itself is wrong — an invariant
+                # violation no capacity growth can fix; surfacing it as a
+                # CapacityError would spin the retry loop growing unrelated
+                # caps against the same miscount forever
+                raise RuntimeError(
+                    "overdelete extraction overflowed its host-counted bound "
+                    f"({n_od_host} rows) — tombstone accounting is inconsistent"
+                )
+            od_rows = np.asarray(rows).reshape(-1, 3)[np.asarray(rv).reshape(-1)]
 
-    (
-        state.marked, state.tomb, state.sorted_keys, state.sort_perm,
-        od_mask, n_od,
-    ) = _finalize_fn(engine)(
-        state.spo, state.epoch, state.marked, state.tomb,
-        state.sorted_keys, state.sort_perm, state.rep,
-    )
-    n_od = int(np.asarray(n_od).reshape(-1)[0])
-    state.stats.overdeleted += n_od
-    yield "overdeleted"
+        (
+            state.marked, state.tomb, state.sorted_keys, state.sort_perm,
+            od_mask, n_od,
+        ) = _finalize_fn(engine)(
+            state.spo, state.epoch, state.marked, state.tomb,
+            state.sorted_keys, state.sort_perm, state.rep,
+        )
+        n_od = int(np.asarray(n_od).reshape(-1)[0])
+        state.stats.overdeleted += n_od
+        yield "overdeleted"
 
-    # -- split: suspect cliques revert to singletons (host rho bookkeeping) --
-    suspect_reps = np.flatnonzero(np.asarray(suspect))
-    state.stats.suspects_split += int(suspect_reps.shape[0])
-    rep_split = split_cliques(rep_host, suspect_reps)
-    p_split, _ = state.base_program.rewrite(rep_split)
-    state.rep = jnp.asarray(rep_split.astype(np.int32))
-    state.program = p_split
-    yield "split"
-    tag.phase = "delete:rederive"
+        # -- split: suspect cliques revert to singletons (host rho) ----------
+        suspect_reps = np.flatnonzero(np.asarray(suspect))
+        state.stats.suspects_split += int(suspect_reps.shape[0])
+        rep_split = split_cliques(rep_host, suspect_reps)
+        p_split, _ = state.base_program.rewrite(rep_split)
+        state.rep = jnp.asarray(rep_split.astype(np.int32))
+        state.program = p_split
+        yield "split"
 
-    # -- rederive: restore overdeleted facts still derivable from survivors --
-    # Targeted (default): for each rule whose head pattern can match an
-    # overdeleted instance, bind the head variables to those instances and
-    # chain the body backward through the persistent sorted index — the
-    # DRed/B-F one-step rederivation, with cost proportional to the
-    # overdelete delta.  The restored instances seed the forward fixpoint,
-    # whose delta discipline finds every consequence.  Whole-rule requeue
-    # (evaluating the rule unconstrained against the surviving store)
-    # remains only for variable-free heads — a head with no variables
-    # admits no instance constraint — and as the "requeue" differential
-    # baseline.
-    od_mask_h = np.asarray(od_mask)
-    requeued = []
-    rederived: list[np.ndarray] = []
-    if n_od:
-        for k, rule in enumerate(p_split.rules):
-            if not _head_may_rederive(rule, od_mask_h, rep_host):
-                continue
-            if engine.rederive_mode != "targeted":
-                requeued.append(k)
-                state.stats.rederive_full_fallback += 1
-                continue
-            bind = _head_bindings(rule, od_rows, rep_host)
-            if bind is None:
-                requeued.append(k)
-                state.stats.rederive_full_fallback += 1
-            elif bind.shape[0]:
-                heads = engine._eval_rule_rederive(state, k, rule, bind)
-                state.stats.rederive_targeted += 1
-                if heads.shape[0]:
-                    rederived.append(heads)
-    yield "rederive"
+    with tag.in_phase("delete:rederive"):
+        # -- rederive: restore overdeleted facts derivable from survivors --
+        # Targeted (default): for each rule whose head pattern can match an
+        # overdeleted instance, bind the head variables to those instances and
+        # chain the body backward through the persistent sorted index — the
+        # DRed/B-F one-step rederivation, with cost proportional to the
+        # overdelete delta.  The restored instances seed the forward fixpoint,
+        # whose delta discipline finds every consequence.  Whole-rule requeue
+        # (evaluating the rule unconstrained against the surviving store)
+        # remains only for variable-free heads — a head with no variables
+        # admits no instance constraint — and as the "requeue" differential
+        # baseline.
+        od_mask_h = np.asarray(od_mask)
+        requeued = []
+        rederived: list[np.ndarray] = []
+        if n_od:
+            for k, rule in enumerate(p_split.rules):
+                if not _head_may_rederive(rule, od_mask_h, rep_host):
+                    continue
+                if engine.rederive_mode != "targeted":
+                    requeued.append(k)
+                    state.stats.rederive_full_fallback += 1
+                    continue
+                bind = _head_bindings(rule, od_rows, rep_host)
+                if bind is None:
+                    requeued.append(k)
+                    state.stats.rederive_full_fallback += 1
+                elif bind.shape[0]:
+                    heads = engine._eval_rule_rederive(state, k, rule, bind)
+                    state.stats.rederive_targeted += 1
+                    if heads.shape[0]:
+                        rederived.append(heads)
+        yield "rederive"
 
-    # seeds: the rederived instances, explicit rows whose (post-split)
-    # normal form went missing, and missing reflexive witnesses of
-    # resources surviving in the store
-    seeds = rederived
-    if explicit_new.shape[0]:
-        nf_exp = rep_split[explicit_new].astype(np.int32)
-        miss = ~_member_query(engine, state, nf_exp)
-        if miss.any():
-            seeds.append(explicit_new[miss])
-    occ = np.asarray(_occ_fn(engine)(state.spo, state.epoch, state.marked, state.rep))
-    if occ.any() and n_od:
-        res = np.union1d(np.flatnonzero(occ), [SAME_AS]).astype(np.int32)
-        refl = np.stack([res, np.full_like(res, SAME_AS), res], axis=1)
-        miss_refl = refl[~_member_query(engine, state, refl)]
-        if miss_refl.shape[0]:
-            seeds.append(miss_refl)
-    cands = (
-        dedup_rows(np.concatenate(seeds, axis=0))
-        if seeds
-        else np.zeros((0, 3), np.int32)
-    )
+        # seeds: the rederived instances, explicit rows whose (post-split)
+        # normal form went missing, and missing reflexive witnesses of
+        # resources surviving in the store
+        seeds = rederived
+        if explicit_new.shape[0]:
+            nf_exp = rep_split[explicit_new].astype(np.int32)
+            miss = ~_member_query(engine, state, nf_exp)
+            if miss.any():
+                seeds.append(explicit_new[miss])
+        occ = np.asarray(_occ_fn(engine)(
+            state.spo, state.epoch, state.marked, state.rep
+        ))
+        if occ.any() and n_od:
+            res = np.union1d(np.flatnonzero(occ), [SAME_AS]).astype(np.int32)
+            refl = np.stack([res, np.full_like(res, SAME_AS), res], axis=1)
+            miss_refl = refl[~_member_query(engine, state, refl)]
+            if miss_refl.shape[0]:
+                seeds.append(miss_refl)
+        cands = (
+            dedup_rows(np.concatenate(seeds, axis=0))
+            if seeds
+            else np.zeros((0, 3), np.int32)
+        )
 
-    state.explicit = explicit_new
-    state.stats.triples_explicit = explicit_new.shape[0]
-    cj, cv = engine._pad_cands(cands)
-    tag.phase = "delete:forward"
-    engine._forward(state, cj, cv, requeued, max_rounds)
+        state.explicit = explicit_new
+        state.stats.triples_explicit = explicit_new.shape[0]
+        cj, cv = engine._pad_cands(cands)
+
+    with tag.in_phase("delete:forward"):
+        engine._forward(state, cj, cv, requeued, max_rounds)
 
 
 def spmd_delete_facts(engine, state: EngineState, delta, max_rounds: int) -> EngineState:
@@ -904,6 +899,9 @@ def static_dispatch_profile(program=None) -> dict:
         # drain varies with the query mix, so it is admissible-unstated.
         "publish": {"snapshot": 1, "rebuild_index": 1},
         "query": {"bgp": None},
+        # the rest of an update's time on the worker, tagged so that its
+        # spans cover the update end to end; none of it dispatches
+        "begin": {}, "barrier": {}, "publish_host": {},
     }
 
 

@@ -2,9 +2,12 @@
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 from collections import Counter
+
+from jax.profiler import TraceAnnotation
 
 
 class DispatchCounter:
@@ -12,8 +15,10 @@ class DispatchCounter:
 
     Every call through the engine's fn cache records one dispatch under
     its *family* (the cache-key head: "plan", "process", "seed_tombs", ...)
-    and, when a maintenance generator has tagged the current phase via the
-    ``phase`` attribute, under that ``(phase, family)`` pair.  First-time
+    and, when a maintenance generator has tagged the current phase with
+    :meth:`in_phase`, under that ``(phase, family)`` pair.  ``in_phase``
+    also opens a profiler span ``store.<phase>``, so a device trace shows
+    which phase launched each program.  First-time
     cache fills are tallied separately in ``compiles`` so steady-state
     dispatch rates can be read net of compilation.  The static half lives
     in :func:`repro.core.incremental_spmd.static_dispatch_profile`;
@@ -42,6 +47,20 @@ class DispatchCounter:
     @phase.setter
     def phase(self, value: str | None) -> None:
         self._phase.value = value
+
+    @contextlib.contextmanager
+    def in_phase(self, phase: str):
+        """Tag this thread's dispatches with ``phase`` inside the block, in
+        a ``jax.profiler.TraceAnnotation`` named ``store.<phase>``; the
+        previous tag comes back on exit.  Inside a generator the tag and
+        the span stay open across its ``yield``s."""
+        prev = self.phase
+        self.phase = phase
+        try:
+            with TraceAnnotation(f"store.{phase}"):
+                yield
+        finally:
+            self.phase = prev
 
     @property
     def total(self) -> int:

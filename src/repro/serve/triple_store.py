@@ -58,7 +58,7 @@ import itertools
 import threading
 import time
 from collections import Counter, deque
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import numpy as np
 
@@ -86,7 +86,8 @@ class UpdateTicket:
 
     ``epoch`` is assigned at the epoch barrier: the first snapshot whose
     fixpoint includes this batch.  ``wall_s`` is admission-to-barrier
-    latency (in cooperative mode it includes any reads interleaved between
+    latency, of which ``queued_s`` waited for the scheduler to begin the
+    update (in cooperative mode it includes any reads interleaved between
     the phases).  ``publish_ms`` is the snapshot publication cost paid at
     this ticket's barrier — reported separately so query latency columns
     measure queries (the BENCH_serve attribution fix).
@@ -98,13 +99,16 @@ class UpdateTicket:
     status: str = "queued"  # queued | running | done | failed
     epoch: int | None = None
     wall_s: float = 0.0
+    queued_s: float = 0.0
     publish_ms: float = 0.0
+    admitted: float = field(default_factory=time.perf_counter)
 
 
 @dataclass
 class QueryTicket:
     """An admitted SPARQL query; ``epoch`` is the completed maintenance
-    epoch whose snapshot the ``answer`` bag was evaluated against."""
+    epoch whose snapshot the ``answer`` bag was evaluated against, and
+    ``wall_s`` its time from admission to answer."""
 
     uid: int
     query: Query
@@ -112,6 +116,7 @@ class QueryTicket:
     epoch: int | None = None
     answer: Counter | None = None
     wall_s: float = 0.0
+    admitted: float = field(default_factory=time.perf_counter)
 
 
 class TripleStore:
@@ -189,7 +194,6 @@ class TripleStore:
         self._inflight: UpdateTicket | None = None
         self._gen = None
         self._snap: dict | None = None
-        self._t_start = 0.0
         # one lock guards admission/queues/pending; the condition on it is
         # the worker's wakeup.  Published-snapshot reads are lock-free
         # (atomic reference load); publication swaps the reference at the
@@ -200,6 +204,7 @@ class TripleStore:
             BatchedExecutor(engine, width=query_width, min_batch=min_batch)
             if batch_queries else None
         )
+        self._lookup_ms = {"device_wait_ms": 0.0, "wall_ms": 0.0}
         self.publish_ms: list[float] = []
         self._published: StoreSnapshot = self._publish()
         self.threaded = bool(threaded)
@@ -252,8 +257,15 @@ class TripleStore:
     @property
     def query_stats(self) -> dict:
         """The batched executor's counters (``batched`` / ``fallback`` /
-        ``overflow`` / ``groups``); empty when batching is off."""
-        return dict(self._batched.stats) if self._batched is not None else {}
+        ``overflow`` / ``groups``) and the drains' times, summed over
+        lookups in ms: ``wall_ms`` from admission to answer, and
+        ``device_wait_ms`` the time the lookup's drain waited for the
+        device's answers (a drain hands its answers out together, so each
+        of its lookups waited for all of them); empty when batching is
+        off."""
+        if self._batched is None:
+            return {}
+        return {**self._batched.stats, **self._lookup_ms}
 
     def audit(self) -> list[str]:
         """Cross-check this store's observed dispatches against the static
@@ -388,8 +400,9 @@ class TripleStore:
         snap = self.engine.publish_snapshot(
             self.state, prev=getattr(self, "_published", None)
         )
-        snap.triples  # noqa: B018  — eager host copy, charged to the barrier
-        snap.rho.members, snap.rho.sizes, snap.rho._csr()  # expansion tables too
+        with self.engine.dispatches.in_phase("publish_host"):
+            snap.triples  # noqa: B018  — eager host copy, charged to the barrier
+            snap.rho.members, snap.rho.sizes, snap.rho._csr()  # expansion tables too
         ms = (time.perf_counter() - t0) * 1e3
         self.publish_ms.append(ms)
         return snap
@@ -410,19 +423,21 @@ class TripleStore:
                 return
             snap = self.snapshot
             if self._batched is not None:
-                t0 = time.perf_counter()
                 res = self._batched.run(
                     [t.query for t in batch], snap, self.dic
                 )
-                per = (time.perf_counter() - t0) / len(batch)
+                waited_ms = self._batched.last_wait_ms
+                done = time.perf_counter()
                 for t, (ans, ep) in zip(batch, res):
                     t.answer, t.epoch = ans, ep
-                    t.wall_s, t.status = per, "done"
+                    t.wall_s, t.status = done - t.admitted, "done"
+                with self._lock:
+                    self._lookup_ms["device_wait_ms"] += waited_ms * len(batch)
+                    self._lookup_ms["wall_ms"] += 1e3 * sum(t.wall_s for t in batch)
             else:
                 for t in batch:
-                    t0 = time.perf_counter()
                     t.answer, t.epoch = evaluate_at(t.query, snap, self.dic)
-                    t.wall_s = time.perf_counter() - t0
+                    t.wall_s = time.perf_counter() - t.admitted
                     t.status = "done"
 
     def _run_one_update(self, t: UpdateTicket) -> None:
@@ -451,13 +466,14 @@ class TripleStore:
         return fn(self.engine, self.state, t.delta, self.max_rounds)
 
     def _begin(self, t: UpdateTicket) -> None:
-        self._inflight = t
-        t.status = "running"
-        self._t_start = time.perf_counter()
-        self.engine._maybe_reset_fallback(self.state)
-        self._snap = self.engine._snapshot(self.state)
-        self._gen = self._make_gen(t)
-        self.inflight_phase = "admitted"
+        with self.engine.dispatches.in_phase("begin"):
+            self._inflight = t
+            t.status = "running"
+            t.queued_s = time.perf_counter() - t.admitted
+            self.engine._maybe_reset_fallback(self.state)
+            self._snap = self.engine._snapshot(self.state)
+            self._gen = self._make_gen(t)
+            self.inflight_phase = "admitted"
 
     def _advance(self) -> None:
         """Advance the in-flight operation by one phase, with capacity retry.
@@ -507,6 +523,6 @@ class TripleStore:
         t.publish_ms = self.publish_ms[-1]
         t.epoch = self.state.update_epoch
         t.status = "done"
-        t.wall_s = time.perf_counter() - self._t_start
+        t.wall_s = time.perf_counter() - t.admitted
         self._inflight, self._gen, self._snap = None, None, None
         self.inflight_phase = None

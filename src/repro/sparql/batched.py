@@ -36,6 +36,8 @@ registers with the trace-audit inventory as the ``"bgp"`` family.
 
 from __future__ import annotations
 
+import threading
+import time
 from dataclasses import dataclass
 from functools import partial
 
@@ -308,6 +310,8 @@ class BatchedExecutor:
     shape, short group, width overflow, host-only snapshot) is invisible in
     the results.  Thread-wise ``run`` is called by one drain at a time (the
     scheduler serialises query drains); the stats dict is advisory.
+    ``last_wait_ms`` is how long the calling thread's last ``run`` waited
+    for its matchers' answers to come back from the device.
     """
 
     def __init__(self, engine, width: int = 4096, min_batch: int = 2,
@@ -318,13 +322,21 @@ class BatchedExecutor:
         self.max_batch = max(int(max_batch), 1)
         self._plans: dict[tuple, BatchPlan | None] = {}
         self.stats = {"batched": 0, "fallback": 0, "overflow": 0, "groups": 0}
+        self._last = threading.local()
 
     def _plan(self, sig) -> BatchPlan | None:
         if sig not in self._plans:
             self._plans[sig] = build_plan(sig)
         return self._plans[sig]
 
+    @property
+    def last_wait_ms(self) -> float:
+        """The ms this thread's last ``run`` spent waiting for the device's
+        answers (the host copies of its groups' outputs)."""
+        return getattr(self._last, "wait_ms", 0.0)
+
     def run(self, queries: list[Query], snapshot, dic) -> list:
+        self._last.wait_ms = 0.0
         results: list = [None] * len(queries)
         if not queries:
             return results
@@ -354,13 +366,16 @@ class BatchedExecutor:
             self.stats["fallback"] += 1
         for sig, idxs in groups.items():
             for at in range(0, len(idxs), self.max_batch):
-                self._run_group(
+                self._last.wait_ms += self._run_group(
                     sig, idxs[at:at + self.max_batch], prepared,
                     queries, snapshot, dic, results,
                 )
         return results
 
-    def _run_group(self, sig, idxs, prepared, queries, snapshot, dic, results):
+    def _run_group(self, sig, idxs, prepared, queries, snapshot, dic,
+                   results) -> float:
+        """Answer one shape group into ``results``; returns the ms spent
+        waiting for the device."""
         plan = self._plans[sig]
         B_pad = _pow2(len(idxs))
         consts = np.zeros((B_pad, max(plan.n_consts, 1)), np.int32)
@@ -371,9 +386,7 @@ class BatchedExecutor:
                 consts[row] = cs
         eng = self.engine
         key = ("bgp", sig, B_pad, self.width, int(snapshot.d_keys.shape[0]))
-        prev_phase = eng.dispatches.phase
-        eng.dispatches.phase = "query"
-        try:
+        with eng.dispatches.in_phase("query"):
             with enable_x64():
                 if key not in eng._fns:
                     eng._register_fn(key, jax.jit(jax.vmap(
@@ -386,11 +399,13 @@ class BatchedExecutor:
                     snapshot.d_triples_pos, snapshot.d_keys_pos,
                     jnp.asarray(consts),
                 )
-        finally:
-            eng.dispatches.phase = prev_phase
-        out = np.asarray(out)
-        valid = np.asarray(valid)
-        overflow = np.asarray(overflow)
+            # the host copy waits for the matcher, and for whatever the
+            # device runs ahead of it (a maintenance program)
+            t0 = time.perf_counter()
+            out = np.asarray(out)
+            valid = np.asarray(valid)
+            overflow = np.asarray(overflow)
+            wait_ms = (time.perf_counter() - t0) * 1e3
         col_of = {cv: k for k, cv in enumerate(plan.var_order)}
         for row, i in enumerate(idxs):
             if overflow[row]:
@@ -410,6 +425,7 @@ class BatchedExecutor:
             )
             self.stats["batched"] += 1
         self.stats["groups"] += 1
+        return wait_ms
 
 
 # ---------------------------------------------------------------------------

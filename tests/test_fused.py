@@ -136,7 +136,8 @@ def test_fused_trace_is_one_while_loop(name):
 def test_retry_dispatches_get_their_own_phase():
     """_recover_capacity re-tags the counter before touching the state, so
     recovery dispatches never masquerade as work of the phase that
-    overflowed — and the crosscheck admits the "retry" phase."""
+    overflowed — and the crosscheck admits the "retry" phase.  The tag
+    that was live comes back once the recovery is over."""
     from repro.analysis import dispatch_crosscheck
 
     gen_kw, seed, _ = _COMBOS[0]
@@ -145,9 +146,14 @@ def test_retry_dispatches_get_their_own_phase():
     state = eng.materialise_state(facts, prog)
 
     snap = eng._snapshot(state)
+    seen = []
+    restore = eng._restore
+    eng._restore = lambda st, sn: (seen.append(eng.dispatches.phase),
+                                   restore(st, sn))
     eng.dispatches.phase = "delete:wave"  # stale tag at overflow time
     eng._recover_capacity(state, snap, CapacityError("bind"))
-    assert eng.dispatches.phase == "retry"
+    assert seen == ["retry"]
+    assert eng.dispatches.phase == "delete:wave"
     assert state.stats.capacity_retries == 1
     eng.dispatches.phase = None
 
