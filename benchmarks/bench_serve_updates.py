@@ -239,7 +239,8 @@ def run_one(
             st = store.state
             jax.block_until_ready(
                 [st.spo, st.epoch, st.marked, st.tomb, st.n_used,
-                 st.rep, st.sort_perm, st.sorted_keys]
+                 st.rep, st.sort_perm, st.sorted_keys, st.pos_perm,
+                 st.pos_keys]
             )
             gc.collect()
             maint_s += time.perf_counter() - s0

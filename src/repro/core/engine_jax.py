@@ -11,11 +11,14 @@ Design:
     paper's outdated bit; marked facts are skipped by matching but retained),
   * delta discipline via epochs: round r matches Delta = (epoch == r-1),
     T_old = (epoch <= r-2), T_all = (epoch <= r-1),
-  * joins  = sort the (small) binding table + searchsorted over packed int64
-    keys with static output capacities and overflow flags (host retries with
-    doubled capacity) — the arena itself is never sorted inside a round,
-  * index  = a persistent sorted view of each shard's live arena rows
-    (``EngineState.sort_perm``/``sorted_keys``), built once and maintained
+  * joins  = range scans on a persistent index for atoms whose fixed
+    positions prefix (s, p, o) or (p, o, s); the rest sort the (small)
+    binding table + searchsorted over packed int64 keys.  Static output
+    capacities and overflow flags (host retries with doubled capacity) —
+    the arena itself is never sorted inside a round,
+  * index  = two persistent sorted views of each shard's live arena rows,
+    in (s, p, o) order (``EngineState.sort_perm``/``sorted_keys``) and in
+    (p, o, s) order (``pos_perm``/``pos_keys``), built once and maintained
     incrementally: fresh rows rank-merge in (:mod:`repro.kernels.merge`),
     swept/finalised rows leave via a stable partition, and a full argsort
     happens at most once per mutation epoch (capacity growth / adoption),
@@ -84,6 +87,20 @@ def _pack3(spo: jnp.ndarray) -> jnp.ndarray:
     p = spo[..., 1].astype(jnp.int64)
     o = spo[..., 2].astype(jnp.int64)
     return (s << 42) | (p << 21) | o
+
+
+def _pack_pos(spo: jnp.ndarray) -> jnp.ndarray:
+    """Packed key in (p, o, s) order: the second index's key, and the
+    published snapshot's secondary view's."""
+    s = spo[..., 0].astype(jnp.int64)
+    p = spo[..., 1].astype(jnp.int64)
+    o = spo[..., 2].astype(jnp.int64)
+    return (p << 42) | (o << 21) | s
+
+
+# key orders of the two persistent arena indexes: key position i holds
+# triple position ORDER[i]
+SPO_ORDER, POS_ORDER = (0, 1, 2), (1, 2, 0)
 
 
 def _pack_cols(cols: list[jnp.ndarray]) -> jnp.ndarray:
@@ -245,15 +262,19 @@ class _AtomSpec:
 
 
 def _index_prefix(spec: _AtomSpec):
-    """Static test: can this atom's join run as persistent-index range scans?
+    """Static test: which persistent index, if any, serves this atom's join?
 
-    True when the atom's *fixed* positions (constants + already-bound
-    variables, including equality duplicates of bound variables) form a
-    prefix of (s, p, o) — the packed-key order of the shared arena index —
-    so each binding's matches are one contiguous key range.  Returns
-    ``(k, components)`` with ``k`` the prefix length and ``components`` the
-    per-position value source (``("const", pos)`` or ``("var", var_id)``),
-    or ``(None, None)`` when the join must fall back to the generic path.
+    An index serves it when the atom's *fixed* positions (constants +
+    already-bound variables, including equality duplicates of bound
+    variables) form a prefix of that index's key order, so each binding's
+    matches are one contiguous key range.  (s, p, o) is tried first, so
+    atoms it serves keep its path; (p, o, s) serves the (p, o)- and
+    p-bound atoms it cannot.  Returns ``(order, k, components)`` with
+    ``order`` the index's key order (:data:`SPO_ORDER` / :data:`POS_ORDER`),
+    ``k`` the prefix length and ``components`` the value source of each
+    prefix position in key order (``("const", pos)`` or ``("var",
+    var_id)``), or ``(None, None, None)`` when the join must fall back to
+    the generic arena-probing path.
     """
     pos_src: dict[int, tuple] = {}
     for v, p in spec.bound_items:
@@ -267,18 +288,44 @@ def _index_prefix(spec: _AtomSpec):
         spec.const_mask[p] or pos_src.get(p, ("free",))[0] == "bound"
         for p in range(3)
     ]
-    k = 0
-    while k < 3 and fixed[k]:
-        k += 1
-    if k == 0 or any(fixed[k:]):
-        return None, None
-    comp = []
-    for p in range(k):
-        if spec.const_mask[p]:
-            comp.append(("const", p))
+    for order in (SPO_ORDER, POS_ORDER):
+        k = 0
+        while k < 3 and fixed[order[k]]:
+            k += 1
+        if k == 0 or any(fixed[p] for p in order[k:]):
+            continue
+        comp = tuple(
+            ("const", p) if spec.const_mask[p] else ("var", pos_src[p][1])
+            for p in order[:k]
+        )
+        return order, k, comp
+    return None, None, None
+
+
+def plan_join_counts(plan, seeded: bool = False) -> tuple[int, int]:
+    """``(index_joins, arena_probe_joins)``: the join steps of one
+    evaluation of ``plan`` that run as index range scans and as the
+    generic arena-probing join.  An unseeded plan (:func:`eval_plan`)
+    opens with a scan of its first atom, which is no join; a seeded one
+    (:func:`eval_plan_rederive`) joins at every step."""
+    n_index = n_arena = 0
+    for step, spec in enumerate(plan):
+        if step == 0 and not seeded and not spec.bound_items:
+            continue
+        if _index_prefix(spec)[0] is None:
+            n_arena += 1
         else:
-            comp.append(("var", pos_src[p][1]))
-    return k, tuple(comp)
+            n_index += 1
+    return n_index, n_arena
+
+
+def count_joins(stats: MatStats, plans, seeded: bool = False, times: int = 1):
+    """Book ``times`` evaluations of each of ``plans`` on ``stats``'s
+    ``index_joins`` / ``arena_probe_joins``."""
+    for plan in plans:
+        n_index, n_arena = plan_join_counts(plan, seeded)
+        stats.index_joins += times * n_index
+        stats.arena_probe_joins += times * n_arena
 
 
 def _atom_static(atom, bound_vars: set[int]):
@@ -296,10 +343,36 @@ def _atom_static(atom, bound_vars: set[int]):
     return const_mask, tuple(eq_pairs), bound, free
 
 
+def _bound_first(body, remaining, bound) -> list[int]:
+    """Greedy join order of the ``remaining`` body atoms: each step takes
+    the first atom sharing a variable with the variables bound so far (else
+    the first left), so bound positions form index key prefixes."""
+    remaining, bound, order = list(remaining), set(bound), []
+    while remaining:
+        j = next(
+            (i for i in remaining
+             if any(is_var(t) and t in bound for t in body[i])),
+            remaining[0],
+        )
+        remaining.remove(j)
+        order.append(j)
+        bound |= {t for t in body[j] if is_var(t)}
+    return order
+
+
 def build_plans(
     rule: Rule, full: bool, tombstone: bool = False
 ) -> list[list[_AtomSpec]]:
     """Delta plans (or the single full-evaluation plan) of a rule.
+
+    Delta plan ``i`` starts at its delta atom ``i`` — a scan of the delta
+    rows — and chains the other atoms greedily bound-first
+    (:func:`_bound_first`), so each join step binds positions that prefix
+    one of the two persistent indexes and runs as range scans
+    (:func:`_index_prefix`) instead of probing the arena.  Each atom keeps
+    its semi-naive predicate (PRED_OLD before the delta atom in body order,
+    PRED_ALL after it) and its body index (``_AtomSpec.index``), so the
+    order changes no match and no count.  The full plan keeps body order.
 
     ``tombstone=True`` builds the DRed overdelete variants: the delta atom
     matches the last overdelete wave (PRED_TDELTA) and every other atom the
@@ -308,12 +381,18 @@ def build_plans(
     """
     assert not (full and tombstone)
     plans = []
-    delta_positions = [0] if full else list(range(len(rule.body)))
+    body = rule.body
+    delta_positions = [0] if full else list(range(len(body)))
     for i in delta_positions:
+        if full:
+            order = list(range(len(body)))
+        else:
+            rest = [j for j in range(len(body)) if j != i]
+            order = [i] + _bound_first(body, rest, {t for t in body[i] if is_var(t)})
         specs = []
         bound: set[int] = set()
-        for j, atom in enumerate(rule.body):
-            const_mask, eq_pairs, b, f = _atom_static(atom, bound)
+        for j in order:
+            const_mask, eq_pairs, b, f = _atom_static(body[j], bound)
             if full:
                 pred = PRED_ALL
             else:
@@ -330,19 +409,23 @@ def build_plans(
 
 
 def _expand_join_index(
-    cols, valid, spo, epoch, marked, tomb, r, sorted_keys, sort_perm,
+    cols, valid, spo, epoch, marked, tomb, r, keys, perm,
     consts, spec: "_AtomSpec", k: int, comp: tuple, out_cap: int,
 ):
     """Index-backed variant of :func:`_expand_join` for prefix-key atoms.
 
+    ``keys``/``perm`` is the persistent index whose key order the atom's
+    fixed positions prefix — (s, p, o) or (p, o, s), per
+    :func:`_index_prefix`, whose ``comp`` lists the prefix in that order.
     Each binding's matches in the live store are one contiguous range of
-    the persistent sorted index (``[pack(prefix, 0..), pack(prefix, max..)]``),
-    so the join is two ``searchsorted`` calls *per binding table* plus the
-    output enumeration — O(bind log C + out) with no arena-length
-    intermediate at all.  Only used for predicates satisfied by every live
-    row (PRED_ALL at evaluation round, PRED_TSTORE), so range counts are
-    exact up to intra-atom equality duplicates, which the post-filter
-    clears (they only cost masked output slots, never correctness).
+    it (``[pack(prefix, 0..), pack(prefix, max..)]``), so the join is two
+    ``searchsorted`` calls *per binding table* plus the output enumeration
+    — O(bind log C + out) with no arena-length intermediate at all.  A
+    range holds every live row with the prefix; matched rows are
+    post-filtered by the atom's predicate (``_epoch_ok(spec.pred)``) and
+    its intra-atom equalities, so rows the predicate drops (older or newer
+    than a PRED_OLD / PRED_DELTA atom admits) cost masked output slots,
+    never correctness.
     """
     maxid = jnp.int64((1 << 21) - 1)
     lo_parts, hi_parts = [], []
@@ -362,8 +445,8 @@ def _expand_join_index(
             hi_parts.append(jnp.broadcast_to(maxid, valid.shape))
     lokey = _pack_cols(lo_parts)
     hikey = _pack_cols(hi_parts)
-    lo = jnp.searchsorted(sorted_keys, lokey, side="left")
-    hi = jnp.searchsorted(sorted_keys, hikey, side="right")
+    lo = jnp.searchsorted(keys, lokey, side="left")
+    hi = jnp.searchsorted(keys, hikey, side="right")
     counts = jnp.where(valid, jnp.maximum(hi - lo, 0), 0)
     cum = jnp.cumsum(counts) - counts  # exclusive
     total = counts.sum()
@@ -371,7 +454,7 @@ def _expand_join_index(
     seg = jnp.searchsorted(cum, j, side="right") - 1
     seg = jnp.clip(seg, 0, valid.shape[0] - 1)
     within = j - cum[seg]
-    srow = sort_perm[jnp.clip(lo[seg] + within, 0, sort_perm.shape[0] - 1)]
+    srow = perm[jnp.clip(lo[seg] + within, 0, perm.shape[0] - 1)]
     out_valid = j < total
     rows = spo[srow]
     okr = _epoch_ok(epoch[srow], marked[srow], tomb[srow], r, spec.pred)
@@ -446,6 +529,8 @@ def eval_plan(
     tomb,
     sorted_keys,
     sort_perm,
+    pos_keys,
+    pos_perm,
     r,
     atom_consts,  # (n_atoms, 3) traced rule constants (vars hold garbage 0)
     head_consts,  # (3,) traced
@@ -463,12 +548,12 @@ def eval_plan(
     global binding table.  The final join's results stay local — their union
     over shards is the global candidate set.
 
-    Atoms whose fixed positions form a packed-key prefix and whose
-    predicate admits every live row (PRED_ALL / PRED_TSTORE) join through
-    the persistent sorted index (:func:`_expand_join_index`) — range scans
-    instead of any arena-length intermediate; the rest take the generic
-    bindings-sorting join.
+    The plan's first atom (the delta atom of a delta plan) is scanned;
+    every later atom joins through :func:`_join_step` — range scans on the
+    (s, p, o) or (p, o, s) index where its fixed positions prefix one,
+    else the generic bindings-sorting join.
     """
+    indexes = {SPO_ORDER: (sorted_keys, sort_perm), POS_ORDER: (pos_keys, pos_perm)}
     cols: dict[int, jnp.ndarray] = {}
     valid = jnp.ones((1,), dtype=bool)  # the unit binding
     n_appl = jnp.zeros((), I32)
@@ -490,7 +575,7 @@ def eval_plan(
         else:
             cols, valid, ov = _join_step(
                 cols, valid, spo, epoch, marked, tomb, r,
-                sorted_keys, sort_perm, atom_consts[spec.index], spec, bind_cap,
+                indexes, atom_consts[spec.index], spec, bind_cap,
                 use_kernel=use_kernel,
             )
         overflow |= ov
@@ -506,22 +591,25 @@ def eval_plan(
 
 
 def _join_step(
-    cols, valid, spo, epoch, marked, tomb, r, sorted_keys, sort_perm,
+    cols, valid, spo, epoch, marked, tomb, r, indexes: dict,
     consts, spec: _AtomSpec, bind_cap: int, use_kernel: bool = False,
 ):
     """One join step of a plan, shared by :func:`eval_plan` and
-    :func:`eval_plan_rederive`: an atom whose fixed positions form a
-    packed-key prefix and whose predicate admits every live row
-    (PRED_ALL / PRED_TSTORE) runs as index range scans; the rest take the
-    generic bindings-sorting join.  Returns ``(cols, valid, overflow)``.
+    :func:`eval_plan_rederive`.  ``indexes`` maps each key order
+    (:data:`SPO_ORDER`, :data:`POS_ORDER`) to its persistent index
+    ``(keys, perm)``.  An atom whose fixed positions prefix either order
+    runs as range scans on that index (:func:`_index_prefix` prefers
+    (s, p, o)), whatever its predicate — the matched rows are
+    post-filtered by it; the rest take the generic bindings-sorting join,
+    which probes every arena row.  Returns ``(cols, valid, overflow)``.
     """
-    if spec.pred in (PRED_ALL, PRED_TSTORE):
-        k, comp = _index_prefix(spec)
-        if k is not None:
-            return _expand_join_index(
-                cols, valid, spo, epoch, marked, tomb, r,
-                sorted_keys, sort_perm, consts, spec, k, comp, bind_cap,
-            )
+    order, k, comp = _index_prefix(spec)
+    if order is not None:
+        keys, perm = indexes[order]
+        return _expand_join_index(
+            cols, valid, spo, epoch, marked, tomb, r,
+            keys, perm, consts, spec, k, comp, bind_cap,
+        )
     ok = _epoch_ok(epoch, marked, tomb, r, spec.pred)
     ok = _match_atom(spo, ok, consts, spec.const_mask, spec.eq_pairs)
     cols, valid, ov, _ = _expand_join(
@@ -560,7 +648,7 @@ def build_rederive_plan(rule: Rule) -> tuple[list[_AtomSpec], tuple[int, ...]]:
     surviving live store (``PRED_TSTORE``).  Body atoms are greedily
     reordered so each step shares a variable with the already-bound set
     where possible — bound positions then form packed-key prefixes and the
-    join runs as range queries on the persistent sorted index.
+    join runs as range queries on a persistent index.
 
     Returns ``(specs, head_vars)`` where ``head_vars`` is the head's
     first-occurrence variable order — the column order the seed table must
@@ -568,16 +656,9 @@ def build_rederive_plan(rule: Rule) -> tuple[list[_AtomSpec], tuple[int, ...]]:
     lookup).
     """
     head_vars = tuple(dict.fromkeys(t for t in rule.head if is_var(t)))
-    remaining = list(range(len(rule.body)))
     bound: set[int] = set(head_vars)
     specs: list[_AtomSpec] = []
-    while remaining:
-        j = next(
-            (i for i in remaining
-             if any(is_var(t) and t in bound for t in rule.body[i])),
-            remaining[0],
-        )
-        remaining.remove(j)
+    for j in _bound_first(rule.body, range(len(rule.body)), bound):
         const_mask, eq_pairs, b, f = _atom_static(rule.body[j], bound)
         specs.append(_AtomSpec(j, const_mask, eq_pairs, b, f, PRED_TSTORE))
         bound |= {v for v, _ in b} | {v for v, _ in f}
@@ -591,6 +672,8 @@ def eval_plan_rederive(
     tomb,
     sorted_keys,
     sort_perm,
+    pos_keys,
+    pos_perm,
     atom_consts,  # (n_atoms, 3) traced rule constants (vars hold garbage 0)
     head_consts,  # (3,) traced
     seeds,        # (seed_cap, n_seed_vars) replicated head-variable bindings
@@ -608,12 +691,13 @@ def eval_plan_rederive(
     The binding table starts from the replicated seed columns instead of an
     arena scan, so every join intermediate — and every sort — scales with
     the overdelete delta, never with the surviving arena.  Atoms whose fixed
-    positions form a packed-key prefix probe the persistent sorted index
+    positions prefix (s, p, o) or (p, o, s) probe that persistent index
     (:func:`_expand_join_index`); the rest take the generic
     bindings-sorting join.  Mirrors :func:`eval_plan`'s SPMD discipline:
     bindings are all_gathered between atoms, the final join's results stay
     local.
     """
+    indexes = {SPO_ORDER: (sorted_keys, sort_perm), POS_ORDER: (pos_keys, pos_perm)}
     r = jnp.zeros((), I32)  # PRED_TSTORE ignores the round counter
     cols = {v: seeds[:, i].astype(I32) for i, v in enumerate(seed_vars)}
     valid = seed_valid
@@ -621,7 +705,7 @@ def eval_plan_rederive(
     for step, spec in enumerate(plan):
         cols, valid, ov = _join_step(
             cols, valid, spo, epoch, marked, tomb, r,
-            sorted_keys, sort_perm, atom_consts[spec.index], spec, bind_cap,
+            indexes, atom_consts[spec.index], spec, bind_cap,
             use_kernel=use_kernel,
         )
         overflow |= ov
@@ -694,19 +778,13 @@ def build_merge_plan(rule: Rule, anchor: int) -> list[_AtomSpec]:
 
     Remaining atoms are ordered greedily bound-first (exactly like
     :func:`build_rederive_plan`) so bound positions form packed-key
-    prefixes for the persistent sorted index.
+    prefixes for a persistent index.
     """
     const_mask, eq_pairs, b, f = _atom_static(rule.body[anchor], set())
     specs = [_AtomSpec(anchor, const_mask, eq_pairs, b, f, PRED_OLD, True)]
     bound = {v for v, _ in b} | {v for v, _ in f}
     remaining = [j for j in range(len(rule.body)) if j != anchor]
-    while remaining:
-        j = next(
-            (i for i in remaining
-             if any(is_var(t) and t in bound for t in rule.body[i])),
-            remaining[0],
-        )
-        remaining.remove(j)
+    for j in _bound_first(rule.body, remaining, bound):
         const_mask, eq_pairs, b, f = _atom_static(rule.body[j], bound)
         specs.append(_AtomSpec(j, const_mask, eq_pairs, b, f, PRED_ALL))
         bound |= {v for v, _ in b} | {v for v, _ in f}
@@ -721,6 +799,8 @@ def process_candidates(
     rep,
     sort_perm,
     sorted_keys,
+    pos_perm,
+    pos_keys,
     cands,
     cand_valid,
     r,
@@ -735,11 +815,13 @@ def process_candidates(
     """Normalise, merge equalities, sweep, insert — the state-update half of a
     round (Algorithms 3-6 in bulk).  Pure; runs per-shard under shard_map.
 
-    ``sort_perm``/``sorted_keys`` is the persistent sorted index of the
-    shard's live rows; it is consumed by the membership probe and returned
-    up to date — swept rows leave via a stable partition, fresh rows (whose
-    keys the dedup step already sorted) rank-merge in.  No step here sorts
-    the arena.
+    ``sort_perm``/``sorted_keys`` and ``pos_perm``/``pos_keys`` are the
+    shard's two persistent indexes of its live rows, in (s, p, o) and
+    (p, o, s) key order; the first is consumed by the membership probe, and
+    both are returned up to date — swept rows leave via a stable partition,
+    fresh rows rank-merge in (their (s, p, o) keys the dedup step already
+    sorted; their (p, o, s) keys are sorted at stream width).  No step here
+    sorts the arena.
 
     Under SPMD there are two exchange schemes:
 
@@ -809,19 +891,19 @@ def process_candidates(
             rewrite_cap,
         )
         rw = jnp.stack([rw_cols["s"], rw_cols["p"], rw_cols["o"]], axis=1)
-        # swept rows leave the persistent index (stable partition, no sort)
+        # swept rows leave both persistent indexes (stable partition, no sort)
         perm, keys = _index_remove(sort_perm, sorted_keys, changed, arena_cap)
-        return rw, rw_valid, rw_overflow, perm, keys
+        pperm, pkeys = _index_remove(pos_perm, pos_keys, changed, arena_cap)
+        return rw, rw_valid, rw_overflow, perm, keys, pperm, pkeys
 
     def _no_sweep(_):
         return (
             jnp.zeros((rewrite_cap, 3), I32), jnp.zeros((rewrite_cap,), bool),
-            jnp.zeros((), bool), sort_perm, sorted_keys,
+            jnp.zeros((), bool), sort_perm, sorted_keys, pos_perm, pos_keys,
         )
 
-    rw, rw_valid, rw_overflow, sort_perm, sorted_keys = jax.lax.cond(
-        changed.any(), _do_sweep, _no_sweep, 0
-    )
+    (rw, rw_valid, rw_overflow, sort_perm, sorted_keys,
+     pos_perm, pos_keys) = jax.lax.cond(changed.any(), _do_sweep, _no_sweep, 0)
     if axis is not None and not routed:
         rw = _gather(rw, axis)
         rw_valid = _gather(rw_valid, axis)
@@ -916,13 +998,26 @@ def process_candidates(
     def _do_merge(_):
         d_keys = jnp.where(dvalid, dcols["k"], KEY_MAX)
         d_vals = jnp.where(dvalid, dcols["v"], arena_cap).astype(I32)
-        return merge_sorted(
+        keys, perm = merge_sorted(
             sorted_keys, sort_perm, d_keys, d_vals,
             out_len=sorted_keys.shape[0],
         )
+        # the (p, o, s) index takes the same fresh rows, sorted by their
+        # (p, o, s) keys at stream width (never the arena)
+        p_keys = jnp.where(fresh, _pack_pos(rows), KEY_MAX)
+        if use_kernel:
+            p_order = kernel_ops.dedup_order(p_keys)
+        else:
+            p_order = argsort_keys(p_keys)
+        pkeys, pperm = merge_sorted(
+            pos_keys, pos_perm, p_keys[p_order], tgt[p_order].astype(I32),
+            out_len=pos_keys.shape[0],
+        )
+        return keys, perm, pkeys, pperm
 
-    sorted_keys, sort_perm = jax.lax.cond(
-        n_fresh > 0, _do_merge, lambda _: (sorted_keys, sort_perm), 0
+    sorted_keys, sort_perm, pos_keys, pos_perm = jax.lax.cond(
+        n_fresh > 0, _do_merge,
+        lambda _: (sorted_keys, sort_perm, pos_keys, pos_perm), 0,
     )
 
     # reflexive-added stat: fresh rows originating from the reflexivity step
@@ -956,7 +1051,10 @@ def process_candidates(
         "delta_rows": delta_rows,
         "delta_valid": dvalid[:d_window],
     }
-    return spo, epoch, marked, n_used[None], rep, sort_perm, sorted_keys, flags
+    return (
+        spo, epoch, marked, n_used[None], rep, sort_perm, sorted_keys,
+        pos_perm, pos_keys, flags,
+    )
 
 
 class CapacityError(RuntimeError):
@@ -966,9 +1064,11 @@ class CapacityError(RuntimeError):
 def index_invariant_report(state: "EngineState", n_shards: int = 1) -> list[str]:
     """Violations of the persistent-index invariant (empty == healthy).
 
-    Per shard block: ``sorted_keys`` must hold exactly the packed keys of
-    the live rows, sorted ascending, as a prefix followed by KEY_MAX
-    padding, and ``sort_perm``'s prefix must enumerate exactly those rows.
+    Per shard block and for each of the two indexes — ``sorted_keys`` /
+    ``sort_perm`` in (s, p, o) order and ``pos_keys`` / ``pos_perm`` in
+    (p, o, s) order: the keys must be exactly the packed keys of the live
+    rows, sorted ascending, as a prefix followed by KEY_MAX padding, and
+    the perm's prefix must enumerate exactly those rows.
     Host-side diagnostic shared by the invariant fuzz tests and debugging;
     states whose index is marked dirty (pending rebuild) are reported as
     such rather than checked.
@@ -981,21 +1081,26 @@ def index_invariant_report(state: "EngineState", n_shards: int = 1) -> list[str]
     spo = np.asarray(state.spo).reshape(n_shards, -1, 3)
     epoch = np.asarray(state.epoch).reshape(n_shards, -1)
     marked = np.asarray(state.marked).reshape(n_shards, -1)
-    keys = np.asarray(state.sorted_keys).reshape(n_shards, -1)
-    perm = np.asarray(state.sort_perm).reshape(n_shards, -1)
-    for s in range(n_shards):
-        live = (epoch[s] >= 0) & ~marked[s]
-        want = np.sort(pack(spo[s][live]))
-        n = want.shape[0]
-        if not (keys[s][n:] == KEY_MAX).all():
-            probs.append(f"shard {s}: non-sentinel entries beyond live prefix")
-        if not np.array_equal(keys[s][:n], want):
-            probs.append(f"shard {s}: sorted_keys != sort(pack3(live rows))")
-        if not np.array_equal(np.sort(perm[s][:n]), np.flatnonzero(live)):
-            probs.append(f"shard {s}: sort_perm prefix is not the live row set")
-        got = pack(spo[s][perm[s][:n]])
-        if not np.array_equal(got, keys[s][:n]):
-            probs.append(f"shard {s}: sort_perm rows disagree with sorted_keys")
+    for name, order, keys, perm in (
+        ("sorted_keys", SPO_ORDER, state.sorted_keys, state.sort_perm),
+        ("pos_keys", POS_ORDER, state.pos_keys, state.pos_perm),
+    ):
+        keys = np.asarray(keys).reshape(n_shards, -1)
+        perm = np.asarray(perm).reshape(n_shards, -1)
+        cols = list(order)
+        for s in range(n_shards):
+            live = (epoch[s] >= 0) & ~marked[s]
+            want = np.sort(pack(spo[s][live][:, cols]))
+            n = want.shape[0]
+            if not (keys[s][n:] == KEY_MAX).all():
+                probs.append(f"shard {s}: {name}: non-sentinel entries beyond live prefix")
+            if not np.array_equal(keys[s][:n], want):
+                probs.append(f"shard {s}: {name} != sort(pack(live rows))")
+            if not np.array_equal(np.sort(perm[s][:n]), np.flatnonzero(live)):
+                probs.append(f"shard {s}: {name}: perm prefix is not the live row set")
+            got = pack(spo[s][perm[s][:n]][:, cols])
+            if not np.array_equal(got, keys[s][:n]):
+                probs.append(f"shard {s}: {name}: perm rows disagree with the keys")
     return probs
 
 
@@ -1015,13 +1120,15 @@ class EngineState:
     the live (``epoch >= 0 & ~marked``) rows in ascending order (KEY_MAX
     padding behind) and ``sort_perm`` the local row index of each entry.
     Every membership probe — store insertion, tombstone seeding/waves,
-    rederive seeds, serving snapshots — binary-searches this shared view;
-    it is maintained *incrementally* (rank-merge on insert, stable
-    partition on sweep/finalize), so the arena is argsorted at most once
-    per mutation epoch: ``index_dirty`` marks the rare rebuild points
-    (capacity growth re-layout) and
-    :meth:`JaxEngine._ensure_index` pays the sort lazily at the next
-    operation's start.
+    rederive seeds, serving snapshots — binary-searches this shared view.
+    ``pos_perm``/``pos_keys`` is the same view in (p, o, s) key order; the
+    joins of atoms whose fixed positions prefix (p, o, s) but not
+    (s, p, o) range-scan it.  Both are maintained *incrementally*, at the
+    same points (rank-merge on insert, stable partition on
+    sweep/finalize), so the arena is argsorted at most once per mutation
+    epoch: ``index_dirty`` marks the rare rebuild points (capacity growth
+    re-layout) and :meth:`JaxEngine._ensure_index` pays the sorts lazily
+    at the next operation's start.
     """
 
     spo: jnp.ndarray
@@ -1032,6 +1139,8 @@ class EngineState:
     rep: jnp.ndarray
     sort_perm: jnp.ndarray
     sorted_keys: jnp.ndarray
+    pos_perm: jnp.ndarray
+    pos_keys: jnp.ndarray
     program: Program
     base_program: Program
     explicit: np.ndarray
@@ -1042,7 +1151,7 @@ class EngineState:
     # (the per-round delta discipline): readers version themselves on this,
     # and it only ever advances at an epoch barrier — never mid-operation.
     update_epoch: int = 0
-    # True when sort_perm/sorted_keys no longer describe the arena (set on
+    # True when the indexes no longer describe the arena (set on
     # capacity re-layout); cleared by JaxEngine._ensure_index
     index_dirty: bool = False
 
@@ -1150,11 +1259,15 @@ def register_auditable(name: str, skip_passes: tuple = ()):
 
 
 def _rebuild_index(spo, epoch, marked):
-    """Full index rebuild: the ONE allowed arena argsort (per mutation epoch)."""
+    """Full rebuild of both indexes: the ONE allowed arena argsort (per
+    mutation epoch), once per key order.  Returns ``(sort_perm,
+    sorted_keys, pos_perm, pos_keys)``."""
     live = (epoch >= 0) & ~marked
     keys = jnp.where(live, _pack3(spo), KEY_MAX)
     perm = argsort_keys(keys)
-    return perm, keys[perm]
+    pkeys = jnp.where(live, _pack_pos(spo), KEY_MAX)
+    pperm = argsort_keys(pkeys)
+    return perm, keys[perm], pperm, pkeys[pperm]
 
 
 def _publish_snapshot(spo, sort_perm, sorted_keys):
@@ -1174,10 +1287,7 @@ def _publish_snapshot(spo, sort_perm, sorted_keys):
     tri = spo[sort_perm]
     live = sorted_keys < KEY_MAX
     n_live = live.sum()
-    s = tri[:, 0].astype(jnp.int64)
-    p = tri[:, 1].astype(jnp.int64)
-    o = tri[:, 2].astype(jnp.int64)
-    pos_keys = jnp.where(live, (p << 42) | (o << 21) | s, KEY_MAX)
+    pos_keys = jnp.where(live, _pack_pos(tri), KEY_MAX)
     perm2 = argsort_keys(pos_keys)
     return tri, sorted_keys, tri[perm2], pos_keys[perm2], n_live
 
@@ -1396,7 +1506,7 @@ class JaxEngine:
             rpl = P() if a else None
             self._jit_fn(
                 plan_key, fn,
-                in_specs=(d, d, d, d, d, d, rpl, rpl, rpl),
+                in_specs=(d, d, d, d, d, d, d, d, rpl, rpl, rpl),
                 out_specs=(d, d, d, d, d, d),
             )
         return self._fns[plan_key]
@@ -1456,8 +1566,8 @@ class JaxEngine:
             }
             self._jit_fn(
                 key, fn,
-                in_specs=(d, d, d, d, rpl, d, d, d, d, rpl),
-                out_specs=(d, d, d, d, rpl, d, d, flag_specs),
+                in_specs=(d, d, d, d, rpl, d, d, d, d, d, d, rpl),
+                out_specs=(d, d, d, d, rpl, d, d, d, d, flag_specs),
             )
         return self._fns[key]
 
@@ -1475,6 +1585,8 @@ class JaxEngine:
             # each shard's trash row (local index ``cap``)
             sort_perm=jnp.full(((cap + 1) * D,), cap, I32),
             sorted_keys=jnp.full(((cap + 1) * D,), KEY_MAX, jnp.int64),
+            pos_perm=jnp.full(((cap + 1) * D,), cap, I32),
+            pos_keys=jnp.full(((cap + 1) * D,), KEY_MAX, jnp.int64),
             program=program,
             base_program=program,
             explicit=np.zeros((0, 3), np.int32),
@@ -1675,7 +1787,7 @@ class JaxEngine:
 
         snap = {f: getattr(state, f) for f in (
             "spo", "epoch", "marked", "tomb", "n_used", "rep",
-            "sort_perm", "sorted_keys", "index_dirty",
+            "sort_perm", "sorted_keys", "pos_perm", "pos_keys", "index_dirty",
             "program", "explicit", "r", "update_epoch",
         )}
         snap["stats"] = copy.copy(state.stats)
@@ -1752,7 +1864,7 @@ class JaxEngine:
     def _ensure_index(self, state: EngineState) -> None:
         """(Re)build the persistent sorted index if it is stale.
 
-        The ONLY full argsort of the arena, paid at most once per mutation
+        The ONLY full argsorts of the arena (one per index), paid at most once per mutation
         epoch — after a capacity re-layout, or to adopt a hand-built state
         — never inside the round loop (``stats.index_rebuilds`` counts the
         sorts so tests can pin that budget).  Must run inside the engine's
@@ -1765,9 +1877,11 @@ class JaxEngine:
             a = self.axis
             d = P(a) if a else None
             self._jit_fn(
-                key, _rebuild_index, in_specs=(d, d, d), out_specs=(d, d),
+                key, _rebuild_index, in_specs=(d, d, d),
+                out_specs=(d, d, d, d),
             )
-        state.sort_perm, state.sorted_keys = self._fns[key](
+        (state.sort_perm, state.sorted_keys,
+         state.pos_perm, state.pos_keys) = self._fns[key](
             state.spo, state.epoch, state.marked
         )
         state.index_dirty = False
@@ -1994,10 +2108,11 @@ class JaxEngine:
         )
         heads, valid, n_d, n_a, ov_bind, ov_out = fn(
             state.spo, state.epoch, state.marked, state.tomb,
-            state.sorted_keys, state.sort_perm,
+            state.sorted_keys, state.sort_perm, state.pos_keys, state.pos_perm,
             jnp.asarray(r, I32),
             jnp.asarray(atom_consts), jnp.asarray(head_consts),
         )
+        count_joins(stats, [plan_t])
         if bool(np.asarray(ov_bind).any()):
             raise CapacityError(self._active_bind_kind)
         if bool(np.asarray(ov_out).any()):
@@ -2058,15 +2173,16 @@ class JaxEngine:
             if rounds_here > max_rounds:
                 raise RuntimeError("did not converge")
             proc = self._get_process_fn(int(cands.shape[0]))
-            spo, epoch, marked, n_used, rep_new, sort_perm, sorted_keys, flags = proc(
+            (spo, epoch, marked, n_used, rep_new, state.sort_perm,
+             state.sorted_keys, state.pos_perm, state.pos_keys, flags) = proc(
                 state.spo, state.epoch, state.marked, state.n_used, state.rep,
                 state.sort_perm, state.sorted_keys,
+                state.pos_perm, state.pos_keys,
                 cands, cand_valid, jnp.asarray(r, I32),
             )
             state.spo, state.epoch, state.marked, state.n_used = (
                 spo, epoch, marked, n_used,
             )
-            state.sort_perm, state.sorted_keys = sort_perm, sorted_keys
             for kind in ("store", "rewrite", "route", "pair"):
                 if bool(np.asarray(flags["ov_" + kind]).any()):
                     raise CapacityError(
@@ -2180,10 +2296,10 @@ class JaxEngine:
             self._jit_fn(
                 key, fn,
                 in_specs=(
-                    d, d, d, d, d, rpl, d, d, d, d,
+                    d, d, d, d, d, rpl, d, d, d, d, d, d,
                     rpl, rpl, rpl, rpl, rpl, rpl,
                 ),
-                out_specs=(d, d, d, d, rpl, d, d, d, d, flag_specs),
+                out_specs=(d, d, d, d, rpl, d, d, d, d, d, d, flag_specs),
             )
         return self._fns[key]
 
@@ -2206,18 +2322,15 @@ class JaxEngine:
         plans_sig = forward_plan_signature(state.program)
         fn = self._get_fused_forward_fn(int(cands.shape[0]), plans_sig)
         ac, hc, cv, cvd = program_tables(state.program)
-        (spo, epoch, marked, n_used, rep, sort_perm, sorted_keys,
+        (state.spo, state.epoch, state.marked, state.n_used, state.rep,
+         state.sort_perm, state.sorted_keys, state.pos_perm, state.pos_keys,
          cands, cand_valid, fl) = fn(
             state.spo, state.epoch, state.marked, state.tomb, state.n_used,
-            state.rep, state.sort_perm, state.sorted_keys, cands, cand_valid,
+            state.rep, state.sort_perm, state.sorted_keys,
+            state.pos_perm, state.pos_keys, cands, cand_valid,
             jnp.asarray(state.r, I32), jnp.asarray(rounds_left, I32),
             ac, hc, cv, cvd,
         )
-        state.spo, state.epoch, state.marked, state.n_used = (
-            spo, epoch, marked, n_used,
-        )
-        state.sort_perm, state.sorted_keys = sort_perm, sorted_keys
-        state.rep = rep
 
         def flag(name: str) -> bool:
             return bool(np.asarray(fl[name]).reshape(-1)[0])
@@ -2225,6 +2338,9 @@ class JaxEngine:
         iters = int(np.asarray(fl["iters"]).reshape(-1)[0])
         state.r += iters
         stats.rounds += iters
+        # every iteration evaluates every plan (the exit one at a nullified
+        # round, still on the device)
+        count_joins(stats, [plan for _k, plan, _h in plans_sig], times=iters)
         stats.sameas_pairs += int(np.asarray(fl["n_pairs"]).reshape(-1)[0])
         n_refl = int(np.asarray(fl["n_reflexive"]).sum())
         stats.reflexive_added += n_refl
@@ -2342,9 +2458,11 @@ class JaxEngine:
             heads, valid, n_d, n_a, ov_bind, ov_out = fn(
                 state.spo, state.epoch, state.marked, state.tomb,
                 state.sorted_keys, state.sort_perm,
+                state.pos_keys, state.pos_perm,
                 jnp.asarray(r, I32),
                 jnp.asarray(atom_consts), jnp.asarray(head_consts),
             )
+            count_joins(state.stats, [plan_t])
             if bool(np.asarray(ov_bind).any()):
                 raise CapacityError(
                     "bind" if full_plan else self._active_bind_kind
@@ -2381,7 +2499,7 @@ class JaxEngine:
             rpl = P() if a else None
             self._jit_fn(
                 key, fn,
-                in_specs=(d, d, d, d, d, d, rpl, rpl, rpl, rpl),
+                in_specs=(d, d, d, d, d, d, d, d, rpl, rpl, rpl, rpl),
                 out_specs=(d, d, d, d, d),
             )
         return self._fns[key]
@@ -2428,10 +2546,11 @@ class JaxEngine:
         )
         out, valid, n_d, ov_bind, ov_out = fn(
             state.spo, state.epoch, state.marked, state.tomb,
-            state.sorted_keys, state.sort_perm,
+            state.sorted_keys, state.sort_perm, state.pos_keys, state.pos_perm,
             jnp.asarray(atom_consts), jnp.asarray(head_consts),
             seeds_j, valid_j,
         )
+        count_joins(stats, [plan], seeded=True)
         if bool(np.asarray(ov_bind).any()):
             raise CapacityError(self._active_bind_kind)
         if bool(np.asarray(ov_out).any()):
@@ -2594,6 +2713,7 @@ def _trace_rule_plans(engine, state, rule, k):
             jx = jax.make_jaxpr(fn)(
                 state.spo, state.epoch, state.marked, state.tomb,
                 state.sorted_keys, state.sort_perm,
+                state.pos_keys, state.pos_perm,
                 jnp.asarray(1, I32), atom_consts, head_consts,
             )
             yield f"plan:rule{k}:{mode}:{i}", jx
@@ -2619,7 +2739,7 @@ def _audit_rplan(engine, state):
         )
         jx = jax.make_jaxpr(fn)(
             state.spo, state.epoch, state.marked, state.tomb,
-            state.sorted_keys, state.sort_perm,
+            state.sorted_keys, state.sort_perm, state.pos_keys, state.pos_perm,
             jnp.zeros((len(rule.body), 3), I32), jnp.zeros((3,), I32),
             jnp.zeros((64, len(seed_vars)), I32), jnp.zeros((64,), bool),
         )
@@ -2644,7 +2764,8 @@ def _audit_mplan(engine, state):
             )
             jx = jax.make_jaxpr(fn)(
                 state.spo, state.epoch, state.marked, state.tomb,
-                state.sorted_keys, state.sort_perm, jnp.asarray(1, I32),
+                state.sorted_keys, state.sort_perm,
+                state.pos_keys, state.pos_perm, jnp.asarray(1, I32),
                 jnp.zeros((len(rule.body), 3), I32), jnp.zeros((3,), I32),
             )
             yield f"mplan:rule{k}:anchor{anchor}", jx
@@ -2661,7 +2782,8 @@ def _audit_process(engine, state):
     cv = jnp.zeros((engine.out_cap,), bool)
     jx = jax.make_jaxpr(fn)(
         state.spo, state.epoch, state.marked, state.n_used, state.rep,
-        state.sort_perm, state.sorted_keys, cands, cv, jnp.asarray(1, I32),
+        state.sort_perm, state.sorted_keys, state.pos_perm, state.pos_keys,
+        cands, cv, jnp.asarray(1, I32),
     )
     yield "process", jx
 
@@ -2678,8 +2800,9 @@ def _audit_squeeze(engine, state):
 
 @register_auditable("rebuild_index", skip_passes=("NoArenaSort",))
 def _audit_rebuild_index(engine, state):
-    # the ONE allowed arena argsort (<= once per mutation epoch, counted by
-    # stats.index_rebuilds) — exempt from NoArenaSort by design
+    # the ONE allowed arena argsort, once per index order (<= once per
+    # mutation epoch, counted by stats.index_rebuilds) — exempt from
+    # NoArenaSort by design
     jx = jax.make_jaxpr(_rebuild_index)(state.spo, state.epoch, state.marked)
     yield "rebuild_index", jx
 

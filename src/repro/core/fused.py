@@ -19,6 +19,14 @@ This module moves the whole inner loop into a single compiled fixpoint:
   plans -> :func:`~repro.core.incremental_spmd._od_step`) fused the same
   way.
 
+Every delta and tombstone plan starts at its delta atom and chains the rest
+bound-first (:func:`~repro.core.engine_jax.build_plans`), so each join step
+range-scans one of the arena's two persistent indexes, in (s, p, o) or
+(p, o, s) order, where its fixed positions prefix one: an iteration's joins
+then scale with the delta, not with the arena.  :func:`fused_forward_rounds`
+carries both indexes and its process step maintains them;
+:func:`fused_delete_waves` reads them as loop constants.
+
 Host-only decisions stay host decisions, but move from per-round to
 per-exit:
 
@@ -142,8 +150,8 @@ def _pany(x, axis):
 
 
 def _eval_plans(
-    spo, epoch, marked, tomb, sorted_keys, sort_perm, r_eval,
-    atom_consts, head_consts, plans, width,
+    spo, epoch, marked, tomb, sorted_keys, sort_perm, pos_keys, pos_perm,
+    r_eval, atom_consts, head_consts, plans, width,
     *, bind_cap, plan_out_cap, axis, use_kernel,
 ):
     """Evaluate the static ``plans`` and squeeze/pad the bucketed heads to
@@ -160,7 +168,8 @@ def _eval_plans(
     ov_squeeze = jnp.zeros((), bool)
     for k, plan_t, head_slots in plans:
         o, v, nd, na, ovb, ovo = eval_plan(
-            spo, epoch, marked, tomb, sorted_keys, sort_perm, r_eval,
+            spo, epoch, marked, tomb, sorted_keys, sort_perm,
+            pos_keys, pos_perm, r_eval,
             atom_consts[k], head_consts[k],
             plan=plan_t, head_var_slots=head_slots,
             bind_cap=bind_cap, out_cap=plan_out_cap, axis=axis,
@@ -190,7 +199,7 @@ def _eval_plans(
 
 def fused_forward_rounds(
     spo, epoch, marked, tomb, n_used, rep, sort_perm, sorted_keys,
-    cands, cand_valid, r0, max_inner,
+    pos_perm, pos_keys, cands, cand_valid, r0, max_inner,
     atom_consts, head_consts, const_vals, const_valid,
     *,
     plans: tuple,
@@ -219,8 +228,12 @@ def fused_forward_rounds(
       host re-runs it with the rewritten constants, or
     * ``max_inner`` iterations ran (host raises "did not converge").
 
+    Both persistent indexes ride in the carry: the process step maintains
+    them and the plans' joins range-scan them.
+
     Returns ``(spo, epoch, marked, n_used, rep, sort_perm, sorted_keys,
-    cands, cand_valid, flags)`` with ``flags`` the exit report (iteration
+    pos_perm, pos_keys, cands, cand_valid, flags)`` with ``flags`` the exit
+    report (iteration
     count, sticky overflow bits, the exit round's ``n_new``, and the
     accumulated stats deltas).
     """
@@ -234,6 +247,7 @@ def fused_forward_rounds(
         "iters": jnp.zeros((), I32),
         "spo": spo, "epoch": epoch, "marked": marked, "n_used": n_used,
         "rep": rep, "sort_perm": sort_perm, "sorted_keys": sorted_keys,
+        "pos_perm": pos_perm, "pos_keys": pos_keys,
         "cands": cands, "cand_valid": cand_valid,
         "have_cands": jnp.ones((), bool),
         "n_new": jnp.zeros((), I32),
@@ -263,11 +277,12 @@ def fused_forward_rounds(
 
     def body(c):
         r = c["r"] + 1
-        (spo_, epoch_, marked_, n_used_, rep_, perm_, keys_, fl) = (
+        (spo_, epoch_, marked_, n_used_, rep_, perm_, keys_, pperm_, pkeys_,
+         fl) = (
             process_candidates(
                 c["spo"], c["epoch"], c["marked"], c["n_used"], c["rep"],
-                c["sort_perm"], c["sorted_keys"], c["cands"], c["cand_valid"],
-                r, rewrite_cap=rewrite_cap, axis=axis, n_shards=n_shards,
+                c["sort_perm"], c["sorted_keys"], c["pos_perm"], c["pos_keys"],
+                c["cands"], c["cand_valid"], r, rewrite_cap=rewrite_cap, axis=axis, n_shards=n_shards,
                 route_cap=route_cap, pair_cap=pair_cap,
                 use_kernel=use_kernel,
             )
@@ -295,7 +310,8 @@ def fused_forward_rounds(
         r_eval = jnp.where(stop, jnp.asarray(_NULL_ROUND, I32), r + 1)
         heads, valid, n_deriv, n_appl, ov_bind, ov_out, ov_squeeze = (
             _eval_plans(
-                spo_, epoch_, marked_, tomb, keys_, perm_, r_eval,
+                spo_, epoch_, marked_, tomb, keys_, perm_, pkeys_, pperm_,
+                r_eval,
                 atom_consts, head_consts, plans, width,
                 bind_cap=bind_cap, plan_out_cap=plan_out_cap, axis=axis,
                 use_kernel=use_kernel,
@@ -306,6 +322,7 @@ def fused_forward_rounds(
             "spo": spo_, "epoch": epoch_, "marked": marked_,
             "n_used": n_used_, "rep": rep_,
             "sort_perm": perm_, "sorted_keys": keys_,
+            "pos_perm": pperm_, "pos_keys": pkeys_,
             "cands": heads, "cand_valid": valid,
             "have_cands": _pany(valid, axis),
             "n_new": n_new,
@@ -337,13 +354,14 @@ def fused_forward_rounds(
     }
     return (
         c["spo"], c["epoch"], c["marked"], c["n_used"], c["rep"],
-        c["sort_perm"], c["sorted_keys"], c["cands"], c["cand_valid"], flags,
+        c["sort_perm"], c["sorted_keys"], c["pos_perm"], c["pos_keys"],
+        c["cands"], c["cand_valid"], flags,
     )
 
 
 def fused_delete_waves(
-    spo, epoch, marked, tomb, sorted_keys, sort_perm, rep, sizes, suspect,
-    max_inner, atom_consts, head_consts,
+    spo, epoch, marked, tomb, sorted_keys, sort_perm, pos_keys, pos_perm,
+    rep, sizes, suspect, max_inner, atom_consts, head_consts,
     *,
     plans: tuple,
     bind_cap: int,
@@ -363,8 +381,9 @@ def fused_delete_waves(
     dead-plan elimination is a host optimisation the fused loop does not
     need).  Exits when a wave tags nothing new, any capacity flag fires, or
     ``max_inner`` waves ran.  The arena columns other than ``tomb`` are
-    loop constants — tombstone tagging never changes liveness, so the
-    persistent sorted index stays exact for every wave's probes.
+    loop constants — tombstone tagging never changes liveness, so both
+    persistent indexes stay exact for every wave's probes and joins, and
+    the loop maintains neither.
 
     Returns ``(tomb, suspect, flags)``.
     """
@@ -395,7 +414,8 @@ def fused_delete_waves(
     def body(c):
         w = c["w"] + 1
         heads, hv, _nd, _na, ov_bind, ov_out, ov_squeeze = _eval_plans(
-            spo, epoch, marked, c["tomb"], sorted_keys, sort_perm, w,
+            spo, epoch, marked, c["tomb"], sorted_keys, sort_perm,
+            pos_keys, pos_perm, w,
             atom_consts, head_consts, plans, plan_out_cap,
             bind_cap=bind_cap, plan_out_cap=plan_out_cap, axis=axis,
             use_kernel=use_kernel,
@@ -433,8 +453,11 @@ def fused_delete_waves(
 #
 # The fused fns join the inventory like every other hot compiled fn: traced
 # single-device, un-jitted, at the caller's probe geometry.  ``fforward``
-# carries no exemptions — the while body's sorts are all delta/bind width
-# and its scatters delta width.  ``fwave`` inlines ``_od_step``, whose
+# carries no exemptions — the while body's sorts are all stream or bind
+# width (the dedup sort, the fresh delta's (p, o, s) sort that feeds the
+# second index's merge, the generic join's binding sort), the arena is only
+# range-probed through its two indexes, and the scatters are delta width.
+# ``fwave`` inlines ``_od_step``, whose
 # per-``n_res`` mask reductions scatter arena-length update streams by
 # design (the od family's documented exemption).
 
@@ -458,6 +481,7 @@ def _audit_fforward(engine, state):
     jx = jax.make_jaxpr(fn)(
         state.spo, state.epoch, state.marked, state.tomb, state.n_used,
         state.rep, state.sort_perm, state.sorted_keys,
+        state.pos_perm, state.pos_keys,
         jnp.zeros((width, 3), I32), jnp.zeros((width,), bool),
         jnp.asarray(1, I32), jnp.asarray(64, I32), ac, hc, cv, cvd,
     )
@@ -476,7 +500,8 @@ def _audit_fwave(engine, state):
     )
     jx = jax.make_jaxpr(fn)(
         state.spo, state.epoch, state.marked, state.tomb,
-        state.sorted_keys, state.sort_perm, state.rep,
+        state.sorted_keys, state.sort_perm, state.pos_keys, state.pos_perm,
+        state.rep,
         jnp.zeros((state.n_res,), I32), jnp.zeros((state.n_res,), bool),
         jnp.asarray(64, I32), ac, hc,
     )

@@ -75,6 +75,7 @@ from .engine_jax import (
     _pow2,
     _route_rows,
     argsort_keys,
+    count_joins,
     register_auditable,
 )
 from repro.kernels import ops as kernel_ops
@@ -246,12 +247,16 @@ def _od_step(
     return tomb, suspect, n_new[None], overflow[None], f_ov[None], od_masks
 
 
-def _finalize_tombs(spo, epoch, marked, tomb, sorted_keys, sort_perm, rep, *, axis):
+def _finalize_tombs(
+    spo, epoch, marked, tomb, sorted_keys, sort_perm, pos_keys, pos_perm, rep,
+    *, axis,
+):
     """Flip tombstones into the paper's outdated bit and reduce the
     per-position masks of overdeleted normal forms (the host-side rederive
     rule filter).  Restores the ``tomb == -1`` forward invariant; the
-    finalised rows leave the persistent index by a stable partition (no
-    sort), keeping it exact for the rederive phase's membership probes."""
+    finalised rows leave both persistent indexes by a stable partition (no
+    sort), keeping them exact for the rederive phase's membership probes
+    and joins."""
     tombed = tomb >= 0
     od_mask = jnp.stack([
         _resource_mask(spo[:, pos], tombed, rep.shape[0]) for pos in range(3)
@@ -265,7 +270,13 @@ def _finalize_tombs(spo, epoch, marked, tomb, sorted_keys, sort_perm, rep, *, ax
     sort_perm, sorted_keys = _index_remove(
         sort_perm, sorted_keys, tombed, spo.shape[0] - 1
     )
-    return marked, tomb, sorted_keys, sort_perm, od_mask, n_od[None]
+    pos_perm, pos_keys = _index_remove(
+        pos_perm, pos_keys, tombed, spo.shape[0] - 1
+    )
+    return (
+        marked, tomb, sorted_keys, sort_perm, pos_keys, pos_perm,
+        od_mask, n_od[None],
+    )
 
 
 def _extract_tombed(spo, tomb, *, axis, cap):
@@ -397,7 +408,7 @@ def _fwave_fn(engine, plans_sig: tuple):
         }
         engine._jit_fn(
             key, fn,
-            in_specs=(d, d, d, d, d, d, rpl, rpl, rpl, rpl, rpl, rpl),
+            in_specs=(d, d, d, d, d, d, d, d, rpl, rpl, rpl, rpl, rpl, rpl),
             out_specs=(d, rpl, flag_specs),
         )
     return engine._fns[key]
@@ -407,7 +418,8 @@ def _finalize_fn(engine):
     d, rpl = _specs(engine)
     return _get_step_fn(
         engine, "finalize_tombs", _finalize_tombs,
-        in_specs=(d, d, d, d, d, d, rpl), out_specs=(d, d, d, d, rpl, rpl),
+        in_specs=(d, d, d, d, d, d, d, d, rpl),
+        out_specs=(d, d, d, d, d, d, rpl, rpl),
     )
 
 
@@ -660,14 +672,19 @@ def spmd_delete_phases(engine, state: EngineState, delta, max_rounds: int):
             ac, hc, _cv, _cvd = program_tables(state.program)
             state.tomb, suspect, fl = fn(
                 state.spo, state.epoch, state.marked, state.tomb,
-                state.sorted_keys, state.sort_perm, state.rep, sizes_j, suspect,
+                state.sorted_keys, state.sort_perm,
+                state.pos_keys, state.pos_perm, state.rep, sizes_j, suspect,
                 jnp.asarray(max_rounds, I32), ac, hc,
             )
 
             def _flag(name: str) -> bool:
                 return bool(np.asarray(fl[name]).reshape(-1)[0])
 
-            state.stats.od_waves += int(np.asarray(fl["iters"]).reshape(-1)[0])
+            waves = int(np.asarray(fl["iters"]).reshape(-1)[0])
+            state.stats.od_waves += waves
+            count_joins(
+                state.stats, [plan for _k, plan, _h in plans_sig], times=waves,
+            )
             if _flag("ov_route"):
                 raise CapacityError("route")
             if _flag("ov_bind"):
@@ -741,10 +758,11 @@ def spmd_delete_phases(engine, state: EngineState, delta, max_rounds: int):
 
         (
             state.marked, state.tomb, state.sorted_keys, state.sort_perm,
-            od_mask, n_od,
+            state.pos_keys, state.pos_perm, od_mask, n_od,
         ) = _finalize_fn(engine)(
             state.spo, state.epoch, state.marked, state.tomb,
-            state.sorted_keys, state.sort_perm, state.rep,
+            state.sorted_keys, state.sort_perm,
+            state.pos_keys, state.pos_perm, state.rep,
         )
         n_od = int(np.asarray(n_od).reshape(-1)[0])
         state.stats.overdeleted += n_od
@@ -951,7 +969,8 @@ def _audit_finalize_tombs(engine, state):
     fn = partial(_finalize_tombs, axis=None)
     jx = jax.make_jaxpr(fn)(
         state.spo, state.epoch, state.marked, state.tomb,
-        state.sorted_keys, state.sort_perm, state.rep,
+        state.sorted_keys, state.sort_perm, state.pos_keys, state.pos_perm,
+        state.rep,
     )
     yield "finalize_tombs", jx
 

@@ -120,6 +120,8 @@ class MatStats:
     remerge_targeted: int = 0       # forward-side rules evaluated merge-anchored
     remerge_full_fallback: int = 0  # forward-side whole-rule requeues (ground atoms)
     delta_mask_fallbacks: int = 0   # delta windows that overflowed to all-True masks
+    index_joins: int = 0            # join steps run as index range scans
+    arena_probe_joins: int = 0      # join steps run as the generic arena probe
     capacity_retries: int = 0       # mid-operation rollback+grow restarts
     wide_growth_restarts: int = 0   # retries that grew a wide (base-run) cap
     triples_total: int = 0          # arena rows used (marked + unmarked)

@@ -360,16 +360,16 @@ def build_engine_cell(spec: ArchSpec, shape: ShapeSpec, mesh) -> Workload:
     head_slots = tuple(t if t < 0 else None for t in rule.head)
 
     def step(spo, epoch, marked, tomb, n_used, rep, sort_perm, sorted_keys,
-             atom_consts, head_consts, r):
+             pos_perm, pos_keys, atom_consts, head_consts, r):
         heads, valid, n_d, n_a, ov_b, ov_o = eval_plan(
-            spo, epoch, marked, tomb, sorted_keys, sort_perm, r,
-            atom_consts, head_consts,
+            spo, epoch, marked, tomb, sorted_keys, sort_perm,
+            pos_keys, pos_perm, r, atom_consts, head_consts,
             plan=plan, head_var_slots=head_slots,
             bind_cap=bind_cap, out_cap=out_cap, axis=axes,
         )
         return process_candidates(
             spo, epoch, marked, n_used, rep, sort_perm, sorted_keys,
-            heads, valid, r,
+            pos_perm, pos_keys, heads, valid, r,
             rewrite_cap=rw_cap, axis=axes, n_shards=n_dev,
             route_cap=cfg.route_cap,
         )
@@ -378,9 +378,10 @@ def build_engine_cell(spec: ArchSpec, shape: ShapeSpec, mesh) -> Workload:
         step,
         mesh=mesh,
         in_specs=(P(axes), P(axes), P(axes), P(axes), P(axes), P(),
-                  P(axes), P(axes), P(), P(), P()),
+                  P(axes), P(axes), P(axes), P(axes), P(), P(), P()),
         out_specs=(
             P(axes), P(axes), P(axes), P(axes), P(), P(axes), P(axes),
+            P(axes), P(axes),
             {
                 "rep_changed": P(), "contradiction": P(),
                 "ov_rewrite": P(axes), "ov_store": P(axes), "ov_route": P(axes),
@@ -398,12 +399,13 @@ def build_engine_cell(spec: ArchSpec, shape: ShapeSpec, mesh) -> Workload:
         _sds((rows,), I32),
         _sds((n_dev,), I32), _sds((n_res,), I32),
         _sds((rows,), I32), _sds((rows,), jnp.int64),
+        _sds((rows,), I32), _sds((rows,), jnp.int64),
         _sds((2, 3), I32), _sds((3,), I32), _sds((), I32),
     )
     in_sh = tuple(
         [_ns(mesh, axes, None), _ns(mesh, axes), _ns(mesh, axes), _ns(mesh, axes),
          _ns(mesh, axes), _ns(mesh), _ns(mesh, axes), _ns(mesh, axes),
-         _ns(mesh), _ns(mesh), _ns(mesh)]
+         _ns(mesh, axes), _ns(mesh, axes), _ns(mesh), _ns(mesh), _ns(mesh)]
     )
     out_sh = None  # let SPMD infer from shard_map out_specs
     # one round over a full arena: joins ~ sort+search over cap rows/device

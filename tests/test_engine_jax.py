@@ -97,3 +97,47 @@ def test_jax_engine_random_equivalence(facts, rules):
     assert _sets_equal(ref.triples(), spo)
     assert (rep == ref.rep).all()
     assert stats.derivations == ref.stats.derivations
+
+
+# ---------------------------------------------------------------------------
+# delta-first plans joined through the two persistent indexes
+# ---------------------------------------------------------------------------
+
+from repro.core.engine_jax import (  # noqa: E402
+    PRED_ALL,
+    PRED_DELTA,
+    PRED_OLD,
+    PRED_TDELTA,
+    PRED_TSTORE,
+    _index_prefix,
+    build_plans,
+    plan_join_counts,
+)
+from repro.data.generator import PROFILES, generate  # noqa: E402
+
+
+@pytest.mark.parametrize("profile", sorted(PROFILES))
+def test_delta_plans_start_at_delta_and_join_through_an_index(profile):
+    """Every delta and tombstone plan of a generator profile scans its delta
+    atom first, keeps each other atom's semi-naive predicate, and joins each
+    later atom by range scans on the (s, p, o) or (p, o, s) index — none
+    probes the arena."""
+    kw = dict(PROFILES[profile], n_groups=4, n_plain=40)
+    _facts, prog, _dic = generate(**kw, seed=1)
+    for rule in prog.rules:
+        for tomb in (False, True):
+            plans = build_plans(rule, full=False, tombstone=tomb)
+            assert len(plans) == len(rule.body)
+            for i, plan in enumerate(plans):
+                assert sorted(s.index for s in plan) == list(range(len(rule.body)))
+                first = plan[0]
+                assert first.index == i and not first.bound_items
+                assert first.pred == (PRED_TDELTA if tomb else PRED_DELTA)
+                assert first.count_appl == (not tomb)
+                for spec in plan[1:]:
+                    want = PRED_TSTORE if tomb else (
+                        PRED_OLD if spec.index < i else PRED_ALL
+                    )
+                    assert spec.pred == want and not spec.count_appl
+                    assert _index_prefix(spec)[0] is not None, (rule, i, spec)
+                assert plan_join_counts(plan) == (len(plan) - 1, 0)

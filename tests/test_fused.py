@@ -85,6 +85,82 @@ def test_fused_vs_host_vs_scratch(gen_kw, seed, _id):
     ), (engines["fused"].dispatches.total, engines["host"].dispatches.total)
 
 
+def _body_order_plans(rule, full, tombstone=False):
+    """:func:`build_plans` with every plan in body order (each atom keeps
+    the predicate it has there): the join order before plans started at
+    their delta atom."""
+    from repro.core import engine_jax as ej
+
+    plans = []
+    for i in [0] if full else range(len(rule.body)):
+        specs, bound = [], set()
+        for j, atom in enumerate(rule.body):
+            cm, eq, b, f = ej._atom_static(atom, bound)
+            if full:
+                pred = ej.PRED_ALL
+            else:
+                pred = ej.PRED_OLD if j < i else (
+                    ej.PRED_DELTA if j == i else ej.PRED_ALL
+                )
+            if tombstone:
+                pred = ej.PRED_TDELTA if pred == ej.PRED_DELTA else ej.PRED_TSTORE
+            count = not tombstone and (pred == ej.PRED_DELTA or (full and j == 0))
+            specs.append(ej._AtomSpec(j, cm, eq, b, f, pred, count))
+            bound |= {v for v, _ in b} | {v for v, _ in f}
+        plans.append(specs)
+    return plans
+
+
+@pytest.mark.parametrize(
+    "gen_kw, seed, _id", _COMBOS, ids=[c[-1] for c in _COMBOS]
+)
+def test_join_order_changes_no_match_and_no_count(gen_kw, seed, _id, monkeypatch):
+    """Delta-first plans, fused and host-looped, and body-order plans give
+    the same store, rho, derivations and rule applications after every
+    event; the base run's counts equal the numpy reference's; and no join
+    step of the delta-first engines probes the arena."""
+    from repro.core import engine_jax, fused
+    from repro.core.materialise import materialise
+
+    facts, prog, dic = generate(**gen_kw, seed=seed)
+    events = sample_update_stream(facts, dic, n_events=4, batch=8, seed=seed)
+    engines = {
+        "fused": _engine(dic, fuse_rounds=True),
+        "host": _engine(dic, fuse_rounds=False),
+        "body_order": _engine(dic, fuse_rounds=True),
+    }
+
+    def call(m, fn, *args):
+        with monkeypatch.context() as mp:
+            if m == "body_order":
+                mp.setattr(engine_jax, "build_plans", _body_order_plans)
+                mp.setattr(fused, "build_plans", _body_order_plans)
+            return fn(*args)
+
+    ref = materialise(facts, prog, dic.n_resources, mode="REW")
+    states = {}
+    for m, e in engines.items():
+        states[m] = call(m, e.materialise_state, facts, prog)
+        st = states[m].stats
+        assert (st.derivations, st.rule_applications) == (
+            ref.stats.derivations, ref.stats.rule_applications
+        ), m
+    for i, (op, delta) in enumerate(events):
+        seen = {}
+        for m, e in engines.items():
+            call(m, e.add_facts if op == "add" else e.delete_facts, states[m], delta)
+            st = states[m].stats
+            seen[m] = (
+                _packset(e.state_triples(states[m])),
+                tuple(e.state_rep(states[m])),
+                st.derivations, st.rule_applications,
+            )
+        assert seen["fused"] == seen["host"] == seen["body_order"], (i, op)
+    for m in ("fused", "host"):
+        st = states[m].stats
+        assert st.arena_probe_joins == 0 and st.index_joins > 0, (m, st)
+
+
 def test_fused_with_dedup_kernel_matches_scratch():
     """use_kernel=True swaps the in-loop argsorts for the counting-rank
     kernel; the fused fixpoint must be bit-equal to the oracle with it."""

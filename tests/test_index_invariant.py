@@ -133,6 +133,57 @@ def test_index_invariant_at_serving_epoch_barriers():
 
 
 # ---------------------------------------------------------------------------
+# the (p, o, s) index after each kind of mutation
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize(
+    "event", ["base", "add", "delete", "merge", "split", "growth"]
+)
+def test_pos_index_invariant(event):
+    """``pos_keys`` holds exactly the live rows' (p, o, s) keys, and
+    ``pos_perm`` their rows, after the base run and after an add, a delete,
+    a clique merge, a clique split and an arena growth."""
+    facts, prog, dic = generate(
+        n_groups=3, group_size=3, n_spokes_per=1, n_plain=24,
+        hierarchy_depth=1, chain_rules=True, seed=6,
+    )
+    t = dic.intern
+    cap = 1 << 10
+    if event == "growth":
+        used = int(np.asarray(_engine(dic).materialise_state(facts, prog).n_used).sum())
+        cap = used + 2
+    eng = JaxEngine(dic.n_resources, capacity=cap, bind_cap=1 << 10,
+                    out_cap=1 << 10, rewrite_cap=1 << 10)
+    state = eng.materialise_state(facts, prog)
+    explicit = facts
+    rows = {
+        "add": [(t(":e0_0"), t(":knows"), t(":p1"))],
+        "delete": [tuple(r) for r in facts if r[1] == t(":knows")][:2],
+        "merge": [(t(":e1_0"), t(":idProp"), t(":idval0"))],
+        "split": [(t(":e0_0"), t(":idProp"), t(":idval0"))],
+        "growth": [(t(":e0_0"), t(":knows"), t(f":p{i}")) for i in range(4)],
+    }.get(event)
+    if rows is not None:
+        op = "delete" if event in ("delete", "split") else "add"
+        delta = np.asarray(rows, np.int32)
+        explicit = apply_op(explicit, op, delta)
+        (eng.add_facts if op == "add" else eng.delete_facts)(state, delta)
+    merged = state.stats.merged_resources
+    if event == "merge":
+        assert merged > 6  # two cliques of three became one of six
+    if event == "split":
+        assert state.stats.suspects_split >= 1
+    if event == "growth":
+        assert eng.capacity > cap and state.stats.index_rebuilds >= 1
+    _assert_clean(eng, state, event)
+    want = np.sort(pack(eng.state_triples(state)[:, [1, 2, 0]]))
+    assert np.array_equal(np.asarray(state.pos_keys)[: want.shape[0]], want)
+    ref = materialise_rew(explicit, prog, dic.n_resources)
+    got = set(pack(eng.state_triples(state)).tolist())
+    assert got == set(pack(ref.triples()).tolist())
+
+
+# ---------------------------------------------------------------------------
 # the sort-op budget: no arena-length sort primitive inside the round fns
 # (the recursive jaxpr walker lives in repro.analysis — shared with the
 # lint CLI, which audits the same inventory through the engine registry)
@@ -174,7 +225,8 @@ def test_no_arena_sort_in_round_fns():
         )
         jx = jax.make_jaxpr(proc)(
             state.spo, state.epoch, state.marked, state.n_used, state.rep,
-            state.sort_perm, state.sorted_keys, cands, cv, jnp.asarray(1, I32),
+            state.sort_perm, state.sorted_keys, state.pos_perm, state.pos_keys,
+            cands, cv, jnp.asarray(1, I32),
         )
         assert _sorts_at_least(jx.jaxpr, arena_rows) == 0
 
@@ -196,6 +248,7 @@ def test_no_arena_sort_in_round_fns():
                     jx = jax.make_jaxpr(fn)(
                         state.spo, state.epoch, state.marked, state.tomb,
                         state.sorted_keys, state.sort_perm,
+                        state.pos_keys, state.pos_perm,
                         jnp.asarray(1, I32), consts, hc,
                     )
                     assert _sorts_at_least(jx.jaxpr, arena_rows) == 0, rule
@@ -223,7 +276,8 @@ def test_no_arena_sort_in_round_fns():
             )
             jx = jax.make_jaxpr(fn)(
                 state.spo, state.epoch, state.marked, state.tomb,
-                state.sorted_keys, state.sort_perm, consts, hc, seeds, sv,
+                state.sorted_keys, state.sort_perm,
+                state.pos_keys, state.pos_perm, consts, hc, seeds, sv,
             )
             assert _sorts_at_least(jx.jaxpr, arena_rows) == 0, rule
 
